@@ -713,6 +713,19 @@ class ReplicaEngine:
                 self._get_fused(decode_chunk_bucket(int(c)), cl)
         return self.compile_s - before
 
+    def compiled_programs(self) -> Dict[Tuple, Any]:
+        """Every AOT executable this replica can dispatch, keyed by
+        (kind, *bucket): ("decode", chunk, ctx) from its own fused cache,
+        ("prefill" | "append" | "shared", ...) from the process-wide prefill
+        cache entries matching its signature. For inspecting what was
+        compiled (`.as_text()`, `.memory_analysis()`)."""
+        progs = {("decode", *k): fn for k, fn in self._fused.items()}
+        sig = self._prefill_cache_key("")[:-1]
+        n = len(sig)
+        progs.update({k[n:]: fn for k, fn in _AOT_PREFILL_CACHE.items()
+                      if k[:n] == sig})
+        return progs
+
     def _remaining_vector(self, emit_mask: np.ndarray,
                           remaining) -> np.ndarray:
         """Normalize `remaining` (scalar or per-slot vector) into a

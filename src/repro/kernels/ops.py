@@ -1,7 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels with backend dispatch:
-on TPU the compiled kernels run natively (interpret=False); elsewhere they
-execute in interpret mode (for validation) or fall back to the jnp
-reference path (`impl="xla"`). The model substrate uses the XLA path for
+on TPU the compiled kernels run natively (interpret=False); on CPU they
+execute in interpret mode (tests and validation); any other backend raises
+rather than interpreting in silence. `impl="xla"` selects the jnp
+reference path instead. The model substrate uses the XLA path for
 the multi-device dry-run (Pallas inside GSPMD is a per-backend concern);
 kernels are selectable via `attention_impl` for single-replica serving."""
 from __future__ import annotations
@@ -18,8 +19,17 @@ from .rglru_kernel import rglru_pallas
 from .rwkv6_kernel import wkv6_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret mode for the backend the kernels are traced on: native on
+    TPU, interpreted on CPU, refused anywhere else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run natively on TPU and interpreted on CPU; "
+        f"backend {backend!r} is neither — use impl='xla'")
 
 
 @partial(jax.jit, static_argnames=("window", "impl"))
@@ -29,7 +39,7 @@ def prefill_attention(q, k, v, *, window: int = 0, impl: str = "pallas"):
         return ref.causal_attention_ref(q, k, v, window=window)
     out = flash_prefill_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), window=window, interpret=not _on_tpu())
+        v.transpose(0, 2, 1, 3), window=window, interpret=_interpret())
     return out.transpose(0, 2, 1, 3)
 
 
@@ -48,7 +58,7 @@ def decode_attention(q, k, v, lengths=None, *, impl: str = "pallas",
             k, v = k[:, :s], v[:, :s]
         return ref.decode_attention_ref(q, k, v, lengths)
     return flash_decode_attention(q, k, v, lengths, max_len=max_len,
-                                  interpret=not _on_tpu())
+                                  interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("impl", "chunk"))
@@ -57,7 +67,7 @@ def wkv6(r, k, v, logw, u, state, *, chunk: int = 32, impl: str = "pallas"):
     if impl == "xla":
         return ref.wkv6_ref(r, k, v, logw, u, state)
     return wkv6_pallas(r, k, v, logw, u, state, chunk=chunk,
-                       interpret=not _on_tpu())
+                       interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("impl", "chunk"))
@@ -65,4 +75,4 @@ def rglru_scan(log_a, b, h0, *, chunk: int = 128, impl: str = "pallas"):
     """Gated linear recurrence h_t = exp(log_a_t) h_{t-1} + b_t."""
     if impl == "xla":
         return ref.rglru_ref(log_a, b, h0)
-    return rglru_pallas(log_a, b, h0, chunk=chunk, interpret=not _on_tpu())
+    return rglru_pallas(log_a, b, h0, chunk=chunk, interpret=_interpret())

@@ -1,7 +1,9 @@
 """Serving launcher: ConServe deployment driver.
 
 Three modes:
-  --engine  : real JAX replicas on local devices (CPU demo / single host)
+  --engine  : real JAX replicas on the local device — the published config
+              in its own dtype (bf16 for qwen3-0.6b) with random weights
+              from --seed; add --reduced for the small fp32 CPU model
   --sim     : the calibrated discrete-event cluster runtime
   default   : lower+compile the serve_step for the production mesh
               (prefill + decode programs for the chosen arch), proving the
@@ -13,7 +15,7 @@ control), so the launcher — like the schedulers — cannot tell the two
 scales apart.
 
   python -m repro.launch.serve --arch qwen3-0.6b [--multi-pod]
-                               [--engine | --sim] [--slots N]
+                               [--engine [--reduced] | --sim] [--slots N]
                                [--gateway] [--scenario NAME] [--seed S]
 
 --scenario picks a named workload from the scenario library
@@ -23,6 +25,28 @@ offline submit+run batch path — same runtime, same records, plus live
 streaming observables.
 """
 import argparse
+
+
+def build_engine(cfg, *, n_slots: int, max_ctx: int,
+                 scheduler: str = "conserve", attention_impl: str = "xla",
+                 seed: int = 0, **server_kw):
+    """The disaggregated engine deployment on the local device: one
+    prefill replica and two decode replicas of `cfg`, sharing one
+    set of random weights drawn from `seed` in the config's dtype, behind
+    an `EngineServer` running `scheduler`. `server_kw` goes to
+    `EngineServer` (rotation, prefill_mode, strict_accounting, ...)."""
+    import jax
+    from repro.core import make_scheduler
+    from repro.engine import EngineServer, ReplicaEngine
+    from repro.models import build_model
+
+    params = build_model(cfg).init(jax.random.PRNGKey(seed))
+    reps = [ReplicaEngine(cfg, params, n_slots=n_slots, max_ctx=max_ctx,
+                          replica_id=i, role="prefill" if i == 0 else "decode",
+                          attention_impl=attention_impl)
+            for i in range(3)]
+    return EngineServer(make_scheduler(scheduler), reps, seed=seed,
+                        **server_kw)
 
 
 def _drive(runtime, trace, gateway: bool = False):
@@ -54,6 +78,10 @@ def main():
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--engine", action="store_true")
+    ap.add_argument("--reduced", action="store_true",
+                    help="engine: serve the small fp32 reduction of --arch "
+                         "(CPU runs and tests) instead of its published "
+                         "config")
     ap.add_argument("--sim", action="store_true")
     ap.add_argument("--scheduler", default="conserve",
                     choices=["conserve", "ampd", "collocated", "full_disagg"])
@@ -82,25 +110,19 @@ def main():
                          "shared_preamble_fleet); default: the classic "
                          "generate_trace workload")
     ap.add_argument("--seed", type=int, default=0,
-                    help="scenario seed (byte-identical trace per seed)")
+                    help="scenario seed (byte-identical trace per seed); "
+                         "engine: also the seed of the random weights")
     args = ap.parse_args()
 
     if args.engine:
-        import jax
-        from repro.configs import get_reduced
-        from repro.core import make_scheduler
-        from repro.engine import EngineServer, ReplicaEngine
-        from repro.models import build_model
+        from repro.configs import get_config, get_reduced
+        from repro.launch.compile_cache import enable_compile_cache
         from repro.traces import TraceConfig, generate_trace
 
-        cfg = get_reduced(args.arch)
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(0))
-        reps = [ReplicaEngine(cfg, params, n_slots=args.slots, max_ctx=1024,
-                              replica_id=0, role="prefill")] + [
-            ReplicaEngine(cfg, params, n_slots=args.slots, max_ctx=1024,
-                          replica_id=i, role="decode") for i in (1, 2)]
-        srv = EngineServer(make_scheduler(args.scheduler), reps,
+        enable_compile_cache()
+        cfg = (get_reduced if args.reduced else get_config)(args.arch)
+        srv = build_engine(cfg, n_slots=args.slots, max_ctx=1024,
+                           scheduler=args.scheduler, seed=args.seed,
                            rotation=not args.no_rotation,
                            prefill_mode=args.prefill_mode)
         if args.scenario:

@@ -7,6 +7,7 @@ Everything is a pure function over explicit param pytrees. Param *skeletons*
 from __future__ import annotations
 
 import math
+import zlib
 from typing import Any, Dict
 
 import jax
@@ -25,14 +26,16 @@ def sds(shape, dtype) -> jax.ShapeDtypeStruct:
 def init_params(skeleton, key) -> Params:
     """Materialize a skeleton with fan-in-scaled normal init.
 
-    Each leaf gets an independent stream derived from the hash of its tree
+    Each leaf gets an independent stream derived from a CRC of its tree
     path, so adding/removing params never reshuffles other leaves (important
-    for checkpoint-compatible config evolution)."""
+    for checkpoint-compatible config evolution). The CRC, unlike the salted
+    built-in `hash`, is the same in every process: one key gives one set of
+    weights everywhere."""
     leaves = jax.tree_util.tree_leaves_with_path(skeleton)
 
     def one(path, leaf):
         path_str = jax.tree_util.keystr(path)
-        k = jax.random.fold_in(key, abs(hash(path_str)) % (2**31))
+        k = jax.random.fold_in(key, zlib.crc32(path_str.encode()) % (2**31))
         name = path_str.rsplit("'", 2)[-2] if "'" in path_str else path_str
         if leaf.ndim == 0:
             return jnp.zeros((), leaf.dtype)

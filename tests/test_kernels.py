@@ -114,3 +114,17 @@ def test_ops_dispatch(key):
     a = ops.prefill_attention(q, k, v, impl="pallas")
     b = ops.prefill_attention(q, k, v, impl="xla")
     assert float(jnp.max(jnp.abs(a - b))) < 2e-5
+
+
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True),
+                                               ("gpu", None)])
+def test_ops_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """Native on TPU, interpreted on CPU, refused anywhere else: a kernel
+    never falls back to interpret mode on an accelerator in silence."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
