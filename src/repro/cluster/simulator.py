@@ -127,7 +127,6 @@ class SimNode:
 class ClusterSimulator(Runtime):
     def __init__(self, scheduler: Scheduler, nodes: List[SimNode],
                  chunk_tokens: int = 8192, decoder_chunk_tokens: int = 2944,
-                 track_token_times: bool = False,
                  tool_deadline_s: Optional[float] = None,
                  tool_timeout_action: str = "evict",
                  strict_accounting: bool = False,
@@ -170,7 +169,6 @@ class ClusterSimulator(Runtime):
                 n.prefix_pool = PrefixKVPool(n.prefix_pool_tokens)
         self.chunk_tokens = chunk_tokens
         self.decoder_chunk_tokens = decoder_chunk_tokens
-        self.track_token_times = track_token_times
         curve = nodes[0].cost.prefill_curve()
         self.view = ClusterView({n.node_id: n.state for n in nodes}, curve)
 

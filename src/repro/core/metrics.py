@@ -26,7 +26,6 @@ class TurnRecord:
     first_token_s: float = 0.0  # TTFT reference point
     last_token_s: float = 0.0
     n_output_tokens: int = 0
-    token_times: Optional[List[float]] = None  # optional full trace
 
     @property
     def ttft_s(self) -> float:

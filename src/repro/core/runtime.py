@@ -59,6 +59,7 @@ from typing import (Any, Callable, Deque, Dict, List, Optional, Sequence,
 from .events import (EV_ADMISSION_ADMIT, EV_ADMISSION_PARK, EV_NODE_JOIN,
                      EV_NODE_QUARANTINE, EV_SESSION, EventBus, ServeEvent)
 from .signals import NODE_ACTIVE, NODE_DRAINING, NODE_QUARANTINED
+from .tracing import ADMISSION, NULL_TRACER, Tracer
 
 # ----- session states --------------------------------------------------------
 QUEUED = "QUEUED"              # submitted / waiting for admission
@@ -448,6 +449,9 @@ class Runtime(abc.ABC):
     quarantine_k: Optional[float] = None
     quarantine_window: int = 3
     quarantine_rejoin_k: Optional[float] = None
+    # spans of the serving loop (core.tracing); the shared tracer stays off,
+    # a backend that traces installs one of its own
+    tracer: Tracer = NULL_TRACER
 
     # ----- protocol ----------------------------------------------------------
     @abc.abstractmethod
@@ -758,10 +762,7 @@ class Runtime(abc.ABC):
         # time, not later from an unrelated conversation's release event
         fits = self._can_admit(node_id, adm)
         if len(q) == 0 and fits:
-            self._publish(EV_ADMISSION_ADMIT, now, cid=adm.cid,
-                          node_id=node_id, kind=adm.kind,
-                          need_tokens=adm.need_tokens)
-            adm.ready(node_id)
+            self._admit(node_id, adm, now)
             return True
         q.push(adm)
         self.view.node(node_id).queued_conversations += 1
@@ -773,6 +774,7 @@ class Runtime(abc.ABC):
             adm.deferred = True
             self.n_deferred_admissions = getattr(
                 self, "n_deferred_admissions", 0) + 1
+        self._trace_admission(adm, parked=True)
         sess = self.sessions.get(adm.cid)
         if sess is not None:
             sess.transition(QUEUED, now)
@@ -820,10 +822,26 @@ class Runtime(abc.ABC):
                 break
             q.remove(cid)
             st.queued_conversations -= 1
-            self._publish(EV_ADMISSION_ADMIT, now, cid=adm.cid,
-                          node_id=node_id, kind=adm.kind,
-                          need_tokens=adm.need_tokens)
-            adm.ready(node_id)
+            self._admit(node_id, adm, now)
+
+    def _admit(self, node_id: int, adm: Admission, now: float):
+        self._publish(EV_ADMISSION_ADMIT, now, cid=adm.cid, node_id=node_id,
+                      kind=adm.kind, need_tokens=adm.need_tokens)
+        self._trace_admission(adm, parked=False)
+        adm.ready(node_id)
+
+    def _trace_admission(self, adm: Admission, parked: bool):
+        """The `engine.admission` span of work that parked: opens at the
+        first park, closes when the work is admitted (a move between queues
+        keeps it open)."""
+        tr = self.tracer
+        if tr.enabled and adm.deferred:
+            sess = self.sessions.get(adm.cid)
+            turn = None if sess is None else sess.turn_idx
+            if parked:
+                tr.begin(ADMISSION, adm.cid, turn, kind=adm.kind)
+            else:
+                tr.end(ADMISSION, adm.cid, turn)
 
     # ----- shared observables -----------------------------------------------
     def queue_waits(self) -> Dict[int, float]:

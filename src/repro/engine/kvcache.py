@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core.runtime import (PrefixKVPool, PrefixPoolEntry,  # noqa: F401
                                 prefix_eviction_order)
+from repro.core.tracing import NULL_TRACER, traced
 from repro.models.model import Model
 
 GROWING = ("k", "v", "ckv", "krope")
@@ -142,6 +143,8 @@ def fold_prefill(caches, new_caches, slot, offset, grouped, growing):
 class SlotKVCache:
     """Owns the cache pytree (batch dim = n_slots) plus per-slot lengths."""
 
+    tracer = NULL_TRACER  # EngineServer installs its own (core.tracing)
+
     def __init__(self, model: Model, n_slots: int, max_ctx: int,
                  replica_id: Optional[int] = None):
         self.model = model
@@ -230,8 +233,11 @@ class SlotKVCache:
         self.lengths[emitted_mask] += 1
 
     # ----- transfer --------------------------------------------------------------
+    @traced("engine.kv_export")
     def export_slot(self, slot: int) -> Dict[str, Any]:
-        """Extract one slot's live cache (for KV transfer between replicas)."""
+        """Extract one slot's live cache (for KV transfer between replicas).
+        With tracing on, the call waits for the copy, so its span measures
+        the move."""
         length = int(self.lengths[slot])
 
         def take(path, leaf, grouped, growing):
@@ -244,10 +250,21 @@ class SlotKVCache:
         tree = jax.tree_util.tree_map_with_path(
             lambda p, l, g, gr: take(p, l, g, gr),
             self.caches, self._grouped, self._growing)
-        return {"caches": tree, "length": length}
+        package = {"caches": tree, "length": length}
+        if self.tracer.enabled:
+            jax.block_until_ready(tree)
+            self.tracer.note(bytes=self.nbytes_of(package), length=length)
+        return package
 
+    @traced("engine.kv_import")
     def import_slot(self, slot: int, package: Dict[str, Any]):
+        """Write an exported slot into `slot` (waited for when tracing, as
+        `export_slot`)."""
         self.write_prefill(slot, package["caches"], package["length"])
+        if self.tracer.enabled:
+            jax.block_until_ready(self.caches)
+            self.tracer.note(bytes=self.nbytes_of(package),
+                             length=package["length"])
 
     def export_slot_full(self, slot: int):
         """Full-buffer prefix view of a slot (right-padded beyond the live

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.tracing import NULL_TRACER, traced
 from repro.models import Model, build_model
 from repro.models.config import ModelConfig
 
@@ -101,7 +102,31 @@ def ctx_bucket(n: int, max_ctx: int) -> int:
     return min(b, max_ctx)
 
 
+def program_name(kind: str, bucket: int, ctx: Optional[int] = None,
+                 n_front: int = 0) -> str:
+    """Stable name of one AOT program: `prefill_b<bucket>`,
+    `append_b<bucket>_c<ctx>`, `shared_b<bucket>_c<ctx>`,
+    `decode_fused_n<steps>_c<ctx>`. The compiled module is `jit_<name>`, in
+    its HLO text and in a device profile's "XLA Modules" line."""
+    if kind == "decode_fused":
+        return f"decode_fused_n{bucket}_c{ctx}"
+    name = f"{kind}_b{bucket}"
+    if ctx is not None:
+        name += f"_c{ctx}"
+    return name + (f"_f{n_front}" if n_front else "")
+
+
+def _named(fn, name: str):
+    """`fn` under `name`, which jax.jit gives the program it builds."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 class ReplicaEngine:
+    # spans of this replica's calls (core.tracing); EngineServer installs
+    # its own tracer on every replica and slot cache it serves
+    tracer = NULL_TRACER
+
     def __init__(self, cfg: ModelConfig, params, *, n_slots: int = 8,
                  max_ctx: int = 2048, replica_id: int = 0, role: str = "decode",
                  warmup: bool = False, attention_impl: str = "xla",
@@ -209,7 +234,7 @@ class ReplicaEngine:
         pad = bucket_len(true_len)
         return pad if pad <= room else true_len
 
-    def _build_prefill(self):
+    def _build_prefill(self, name: str = "prefill"):
         """Turn-1 prefill program builder (the token bucket and frontend
         shape are fixed by the .lower() specs at the _get_prefill call
         site): forward over the padded bucket, logits gathered at the
@@ -231,9 +256,9 @@ class ReplicaEngine:
             tok = jnp.argmax(logits[0, :vocab]).astype(jnp.int32)
             return caches, tok
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(_named(run, name), donate_argnums=(1,))
 
-    def _build_append(self, ctx: int):
+    def _build_append(self, ctx: int, name: Optional[str] = None):
         """Append-prefill program for one prefix ctx bucket (the token
         bucket is fixed by the .lower() specs at the _get_append call site):
         the hot prefix is a dynamic slice of the slot's own cache rows
@@ -257,7 +282,8 @@ class ReplicaEngine:
             tok = jnp.argmax(logits[0, :vocab]).astype(jnp.int32)
             return caches, tok
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(_named(run, name or f"append_c{ctx}"),
+                       donate_argnums=(1,))
 
     def _aot_specs(self):
         spec = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
@@ -283,7 +309,8 @@ class ReplicaEngine:
             scalar = jax.ShapeDtypeStruct((), jnp.int32)
             fe_spec = None if not n_front else jax.ShapeDtypeStruct(
                 (1, n_front, self.cfg.d_model), self.cfg.jnp_dtype)
-            fn = self._build_prefill().lower(
+            fn = self._build_prefill(
+                program_name("prefill", pad_to, n_front=n_front)).lower(
                 pspec, cspec, jax.ShapeDtypeStruct((pad_to,), jnp.int32),
                 scalar, scalar, fe_spec).compile()
             self.compile_s += time.perf_counter() - t0
@@ -299,14 +326,15 @@ class ReplicaEngine:
             t0 = time.perf_counter()
             pspec, cspec = self._aot_specs()
             scalar = jax.ShapeDtypeStruct((), jnp.int32)
-            fn = self._build_append(ctx).lower(
+            fn = self._build_append(
+                ctx, program_name("append", pad_to, ctx)).lower(
                 pspec, cspec, jax.ShapeDtypeStruct((pad_to,), jnp.int32),
                 scalar, scalar, scalar).compile()
             self.compile_s += time.perf_counter() - t0
             _AOT_PREFILL_CACHE[key] = fn
         return fn
 
-    def _build_shared(self, ctx: int):
+    def _build_shared(self, ctx: int, name: str):
         """Shared-prefix prefill program for one pooled ctx bucket (the
         delta-token bucket is fixed by the .lower() specs at the _get_shared
         call site) — the third prefill class: append-against-shared-prefix.
@@ -336,7 +364,7 @@ class ReplicaEngine:
             tok = jnp.argmax(logits[0, :vocab]).astype(jnp.int32)
             return caches, tok
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(_named(run, name), donate_argnums=(1,))
 
     def _pool_specs(self, ctx: int):
         """ShapeDtypeStructs of a pooled entry at ctx bucket `ctx` — by
@@ -357,7 +385,8 @@ class ReplicaEngine:
             t0 = time.perf_counter()
             pspec, cspec = self._aot_specs()
             scalar = jax.ShapeDtypeStruct((), jnp.int32)
-            fn = self._build_shared(ctx).lower(
+            fn = self._build_shared(
+                ctx, program_name("shared", pad_to, ctx)).lower(
                 pspec, cspec, self._pool_specs(ctx),
                 jax.ShapeDtypeStruct((pad_to,), jnp.int32),
                 scalar, scalar, scalar).compile()
@@ -403,6 +432,7 @@ class ReplicaEngine:
                         self._get_shared(L, C)
         return self.compile_s - before
 
+    @traced("engine.prefill")
     def prefill_conversation(self, slot: int, tokens: np.ndarray,
                              frontend_embeds=None, prefix_len: int = 0
                              ) -> Tuple[np.ndarray, float]:
@@ -455,6 +485,9 @@ class ReplicaEngine:
         self.compute_s += dt
         self.prefill_s += dt
         self.n_prefill_tokens += true_len
+        self.tracer.timed(t0, dt, tokens=true_len, bucket=pad_to,
+                          program=program_name("prefill", pad_to,
+                                               n_front=n_front))
         return np.int32(tok), dt
 
     def _prefill_split(self, slot: int, tokens: np.ndarray, prefix_len: int
@@ -555,6 +588,8 @@ class ReplicaEngine:
             self.prefill_s += dt
             self.n_prefill_tokens += true_len
             self.n_pooled_prefix_tokens += prefix_len
+            self.tracer.timed(t0, dt, tokens=true_len, bucket=pad_to,
+                              program=program_name("shared", pad_to, e.ctx))
             return np.int32(tok), dt
         finally:
             pool.unpin(key)
@@ -580,8 +615,11 @@ class ReplicaEngine:
         self.compute_s += dt
         self.prefill_s += dt
         self.n_prefill_tokens += true_len
+        self.tracer.timed(t0, dt, tokens=true_len, bucket=pad_to,
+                          program="prefill_reference")
         return self.sample(logits)[0], dt
 
+    @traced("engine.append")
     def append_prefill(self, slot: int, tokens: np.ndarray
                        ) -> Tuple[np.ndarray, float]:
         """Turn-2+ prefill against the slot's cached prefix (local, prefix
@@ -607,6 +645,8 @@ class ReplicaEngine:
         self.compute_s += dt
         self.prefill_s += dt
         self.n_prefill_tokens += true_len
+        self.tracer.timed(t0, dt, tokens=true_len, bucket=pad_to,
+                          program=program_name("append", pad_to, ctx))
         return np.int32(tok), dt
 
     def _append_reference(self, slot: int, tokens: np.ndarray
@@ -633,6 +673,8 @@ class ReplicaEngine:
         self.compute_s += dt
         self.prefill_s += dt
         self.n_prefill_tokens += true_len
+        self.tracer.timed(t0, dt, tokens=true_len, bucket=pad_to,
+                          program="append_reference")
         return self.sample(logits)[0], dt
 
     # ----- decode -----------------------------------------------------------------
@@ -657,11 +699,13 @@ class ReplicaEngine:
                     params, tokens, caches, lens, kv_lens=lens,
                     ctx_limit=ctx_limit,
                     attention_impl=self.attention_impl)
-                sampled = jnp.argmax(logits[:, :vocab], axis=-1).astype(
-                    jnp.int32)
+                with jax.named_scope("sample"):
+                    sampled = jnp.argmax(logits[:, :vocab], axis=-1).astype(
+                        jnp.int32)
                 live = emit & (i < remaining)
-                caches = fold_decode_step(caches, updates, lens, live,
-                                          grouped, growing)
+                with jax.named_scope("kv_fold"):
+                    caches = fold_decode_step(caches, updates, lens, live,
+                                              grouped, growing)
                 lens = lens + live.astype(lens.dtype)
                 tokens = jnp.where(live, sampled, tokens)
                 return (caches, lens, tokens), sampled
@@ -670,7 +714,9 @@ class ReplicaEngine:
                 body, (caches, lens, tokens), jnp.arange(n_steps))
             return caches, seq
 
-        return jax.jit(run, donate_argnums=(1,))
+        return jax.jit(
+            _named(run, program_name("decode_fused", n_steps, ctx_limit)),
+            donate_argnums=(1,))
 
     def _get_fused(self, n_steps: int, ctx_limit: int):
         """Fetch (or AOT-compile) the fused program for one (chunk, ctx)
@@ -769,6 +815,7 @@ class ReplicaEngine:
                 f"tokens (max_ctx={self.kv.max_ctx})")
         return rem
 
+    @traced("engine.decode_chunk")
     def decode_steps(self, next_tokens: np.ndarray, emit_mask: np.ndarray,
                      remaining) -> Tuple[np.ndarray, float]:
         """Run one RAGGED fused decode chunk across ALL slots in ONE
@@ -813,13 +860,21 @@ class ReplicaEngine:
                          jnp.asarray(next_tokens, jnp.int32),
                          jnp.asarray(self.kv.lengths),
                          jnp.asarray(emit_mask), jnp.asarray(rem))
-        seq = np.asarray(jax.block_until_ready(seq))[:n_max]
+        with self.tracer.span("engine.device_wait"):
+            seq = jax.block_until_ready(seq)
+        seq = np.asarray(seq)[:n_max]
         self.kv.caches = caches  # donated: old buffers are dead
         self.kv.lengths += np.where(emit_mask, rem, 0).astype(np.int32)
         dt = time.perf_counter() - t0
         self.compute_s += dt
         self.decode_s += dt
-        self.n_decode_tokens += int(rem[emit_mask].sum())
+        live = int(rem[emit_mask].sum())
+        self.n_decode_tokens += live
+        self.tracer.timed(t0, dt, node=self.replica_id,
+                          program=program_name("decode_fused", n_steps,
+                                               ctx_limit),
+                          n_steps=n_steps, ctx=ctx_limit,
+                          lanes=int(emit_mask.sum()), live=live)
         return seq, dt
 
     def decode_step_all(self, next_tokens: np.ndarray,
@@ -829,6 +884,7 @@ class ReplicaEngine:
         seq, dt = self.decode_steps(next_tokens, emit_mask, 1)
         return seq[0], dt
 
+    @traced("engine.decode_chunk")
     def decode_step_all_reference(self, next_tokens: np.ndarray,
                                   emit_mask: np.ndarray
                                   ) -> Tuple[np.ndarray, float]:
@@ -840,10 +896,15 @@ class ReplicaEngine:
         logits, updates = self._decode(
             self.params, jnp.asarray(next_tokens), self.kv.caches,
             self.kv.positions(), lens)
-        logits = jax.block_until_ready(logits)
+        with self.tracer.span("engine.device_wait"):
+            logits = jax.block_until_ready(logits)
         self.kv.append_step(updates, emit_mask)
         dt = time.perf_counter() - t0
         self.compute_s += dt
         self.decode_s += dt
-        self.n_decode_tokens += int(emit_mask.sum())
+        lanes = int(emit_mask.sum())
+        self.n_decode_tokens += lanes
+        self.tracer.timed(t0, dt, node=self.replica_id,
+                          program="decode_reference", n_steps=1,
+                          ctx=self.kv.max_ctx, lanes=lanes, live=lanes)
         return self.sample(logits), dt

@@ -68,6 +68,7 @@ from repro.core.runtime import (Admission, AdmissionQueue,
 from repro.core.scheduler import Scheduler
 from repro.core.signals import (NODE_ACTIVE, ClusterView, NodeState,
                                 PrefillLatencyCurve)
+from repro.core.tracing import TURN, Tracer
 
 from .kvcache import prefix_hash
 from .replica import DECODE_CHUNKS, ReplicaEngine, decode_chunk_floor
@@ -104,7 +105,8 @@ class EngineServer(Runtime):
                  transfer_retry_backoff_s: float = 0.01,
                  quarantine_k: Optional[float] = None,
                  quarantine_window: int = 3,
-                 quarantine_rejoin_k: Optional[float] = None):
+                 quarantine_rejoin_k: Optional[float] = None,
+                 tracing: bool = False):
         """decode_mode: "fused" runs up to `max_decode_chunk` tokens per
         dispatch through the donated in-place RAGGED scan (`decode_steps`):
         each slot consumes only its own per-slot share, and turns that
@@ -154,7 +156,11 @@ class EngineServer(Runtime):
         quarantine_k × the fleet median for quarantine_window consecutive
         decode chunks leaves the schedulable set (lifecycle QUARANTINED),
         and requalifies once it falls back below quarantine_rejoin_k ×
-        median (defaults to quarantine_k) for the same window."""
+        median (defaults to quarantine_k) for the same window.
+        tracing: record the serving loop's spans and compile events
+        (`core.tracing`) in `self.tracer`, which this server hands to its
+        replicas and their slot caches. Off by default; `tracer.enable()`
+        turns it on later."""
         assert decode_mode in ("fused", "reference")
         assert prefill_mode in (None, "jit", "reference")
         assert tool_timeout_action in ("evict", "fail")
@@ -163,6 +169,9 @@ class EngineServer(Runtime):
                 r.prefill_mode = prefill_mode
         self.sched = scheduler
         self.replicas = {r.replica_id: r for r in replicas}
+        self.tracer = Tracer(enabled=tracing)
+        for r in replicas:
+            r.tracer = r.kv.tracer = self.tracer
         self.link_bw = link_bw_bytes_s
         # compiled scan buckets top out at DECODE_CHUNKS[-1]; a larger chunk
         # would silently desync server token accounting from the replica
@@ -211,7 +220,6 @@ class EngineServer(Runtime):
         self._events: List[Tuple[float, int, object]] = []
         self._seq = itertools.count()
         self._now = 0.0
-        self.transfer_bytes = 0.0
         self.n_transfers = 0
         # ----- failure contract state -----
         self.tool_deadline_s = tool_deadline_s
@@ -357,6 +365,8 @@ class EngineServer(Runtime):
             self._convs[c.cid] = c
             self.records[c.cid] = ConversationRecord(c.cid, c.arrival_s)
             self._make_session(c.cid, c.arrival_s)
+            if c.arrival_s <= self._now:
+                self.tracer.begin(TURN, c.cid, 0)
             # staged arrival injection: a submission landing after logical
             # time passed its arrival stamp executes at now (the logical
             # clock must never run backwards); the session keeps the trace's
@@ -452,6 +462,7 @@ class EngineServer(Runtime):
 
     # ----- arrival & turn-1 prefill -------------------------------------------------
     def _arrive(self, conv: Conversation):
+        self.tracer.begin(TURN, conv.cid, 0)
         pl = self.sched.place_first_prefill(view_of(conv), self.view)
         st = self.states[pl.node_id]
         # backlog observable covers parked + admitted-unstarted prefill
@@ -499,8 +510,9 @@ class EngineServer(Runtime):
         if node.cfg.frontend != "none":
             fe = jnp.zeros((1, node.cfg.frontend_len or node.cfg.encoder_seq,
                             node.cfg.d_model), node.cfg.jnp_dtype)
-        next_tok, dt = node.prefill_conversation(
-            slot, tokens, fe, prefix_len=self._prefix_split(conv, node))
+        with self.tracer.resume(conv.cid, 0):
+            next_tok, dt = node.prefill_conversation(
+                slot, tokens, fe, prefix_len=self._prefix_split(conv, node))
         dt = self._stretched(node_id, dt)
         self._sync_pool_state(node_id)
         done_t = start + dt
@@ -520,7 +532,8 @@ class EngineServer(Runtime):
         # slot frees NOW (the package travels host-side); the binding itself
         # must pass admission on the decoder.
         bind = self.sched.bind_decoder(view_of(conv), self.view)
-        pkg = node.kv.export_slot(slot)
+        with self.tracer.resume(conv.cid, 0):
+            pkg = node.kv.export_slot(slot)
         node.kv.release(slot)
         st.used_slots -= 1
         st.active_kv_tokens -= written
@@ -583,10 +596,10 @@ class EngineServer(Runtime):
         self._bind_attempts.pop(conv.cid, None)
         dslot = dec.kv.acquire()
         st.used_slots += 1
-        dec.kv.import_slot(dslot, pkg)
+        with self.tracer.resume(conv.cid, turn_idx):
+            dec.kv.import_slot(dslot, pkg)
         st.active_kv_tokens += pkg["length"]
         nbytes = dec.kv.nbytes_of(pkg)
-        self.transfer_bytes += nbytes
         self.n_transfers += 1
         self.records[conv.cid].n_kv_transfers += 1
         xfer_t = nbytes / self.link_bw + 0.005
@@ -638,6 +651,7 @@ class EngineServer(Runtime):
         # concatenating `tokens` payloads reproduce task.stream exactly
         self._publish(EV_TOKENS, ready_t, cid=conv.cid, turn_idx=turn_idx,
                       node_id=node_id, tokens=[next_tok], per_token_s=0.0)
+        self.tracer.end(TURN, conv.cid, turn_idx)
         if self.rotation:
             self._ready[node_id].append((ready_t, next(self._seq), task))
             self._kick(node_id, ready_t)
@@ -878,6 +892,7 @@ class EngineServer(Runtime):
 
     # ----- turn 2+ --------------------------------------------------------------------
     def _next_turn(self, conv: Conversation, idx: int, ready_t: float):
+        self.tracer.begin(TURN, conv.cid, idx)
         binding = self._slots.get(conv.cid)
         if binding is None or not self.states[binding[0]].alive:
             # the tool returned to a dead binding (replica failed during
@@ -902,7 +917,8 @@ class EngineServer(Runtime):
             # slot is already held, so no admission is involved
             start = max(ready_t, self.clock[node_id])
             self.sessions[conv.cid].transition(PREFILLING, start)
-            next_tok, dt = node.append_prefill(slot, tokens)
+            with self.tracer.resume(conv.cid, idx):
+                next_tok, dt = node.append_prefill(slot, tokens)
             dt = self._stretched(node_id, dt)
             self.clock[node_id] = start + dt
             self.states[node_id].active_kv_tokens += len(tokens)
@@ -930,26 +946,26 @@ class EngineServer(Runtime):
         rst = self.states[remote_id]
         tokens = self._turn_tokens(conv, idx)
         self.sessions[conv.cid].transition(TRANSFERRING, ready_t)
-        pkg = node.kv.export_slot(slot)
-        nbytes = node.kv.nbytes_of(pkg)
-        rslot = remote.kv.acquire()
-        rst.used_slots += 1
-        remote.kv.import_slot(rslot, pkg)
-        rst.active_kv_tokens += pkg["length"]
-        t0 = max(ready_t, self.clock[remote_id]) + nbytes / self.link_bw
-        self.sessions[conv.cid].transition(PREFILLING, t0)
-        next_tok, dt = remote.append_prefill(rslot, tokens)
-        dt = self._stretched(remote_id, dt)
-        # the append landed in the remote slot: mirror it before the release
-        # below subtracts the slot's full (grown) length
-        rst.active_kv_tokens += len(tokens)
-        pkg2 = remote.kv.export_slot(rslot)
-        nbytes2 = remote.kv.nbytes_of(pkg2)
-        rst.active_kv_tokens -= int(remote.kv.lengths[rslot])
-        remote.kv.release(rslot)
-        rst.used_slots -= 1
-        node.kv.import_slot(slot, pkg2)
-        self.transfer_bytes += nbytes + nbytes2
+        with self.tracer.resume(conv.cid, idx):
+            pkg = node.kv.export_slot(slot)
+            nbytes = node.kv.nbytes_of(pkg)
+            rslot = remote.kv.acquire()
+            rst.used_slots += 1
+            remote.kv.import_slot(rslot, pkg)
+            rst.active_kv_tokens += pkg["length"]
+            t0 = max(ready_t, self.clock[remote_id]) + nbytes / self.link_bw
+            self.sessions[conv.cid].transition(PREFILLING, t0)
+            next_tok, dt = remote.append_prefill(rslot, tokens)
+            dt = self._stretched(remote_id, dt)
+            # the append landed in the remote slot: mirror it before the
+            # release below subtracts the slot's full (grown) length
+            rst.active_kv_tokens += len(tokens)
+            pkg2 = remote.kv.export_slot(rslot)
+            nbytes2 = remote.kv.nbytes_of(pkg2)
+            rst.active_kv_tokens -= int(remote.kv.lengths[rslot])
+            remote.kv.release(rslot)
+            rst.used_slots -= 1
+            node.kv.import_slot(slot, pkg2)
         self.n_transfers += 2
         done = t0 + dt + nbytes2 / self.link_bw
         self.clock[remote_id] = t0 + dt
@@ -1169,8 +1185,9 @@ class EngineServer(Runtime):
         # did (the journaled ctx opens with it), so the rebuilt stream is
         # byte-identical to the failure-free run and the healthy node's
         # pool serves/repopulates the preamble exactly like a fresh arrival
-        next_tok, dt = node.prefill_conversation(
-            slot, ctx, fe, prefix_len=self._prefix_split(conv, node))
+        with self.tracer.resume(conv.cid, turn_idx):
+            next_tok, dt = node.prefill_conversation(
+                slot, ctx, fe, prefix_len=self._prefix_split(conv, node))
         dt = self._stretched(node_id, dt)
         self._sync_pool_state(node_id)
         done_t = start + dt
@@ -1183,7 +1200,8 @@ class EngineServer(Runtime):
             self._bind_done(conv, node_id, slot, int(next_tok), done_t,
                             turn_idx=turn_idx, arrival_t=arrival_t)
             return
-        pkg = node.kv.export_slot(slot)
+        with self.tracer.resume(conv.cid, turn_idx):
+            pkg = node.kv.export_slot(slot)
         node.kv.release(slot)
         st.used_slots -= 1
         st.active_kv_tokens -= written

@@ -439,55 +439,61 @@ def gqa_prefill(params, cfg: ModelConfig, kind: str, x, start_pos: int,
     kv_lens masks and sliding windows fall back to the jnp paths below.
     """
     B, S, _ = x.shape
-    q, k, v = _proj_qkv(params, cfg, x)
-    theta = cfg.rope_theta if kind == ATTN_GLOBAL else getattr(
-        cfg, "rope_theta_local", cfg.rope_theta)
-    pos = start_pos + jnp.arange(S)
-    q = apply_rope(q, pos, theta)
-    k = apply_rope(k, pos, theta)
+    with jax.named_scope("qkv"):
+        q, k, v = _proj_qkv(params, cfg, x)
+        theta = cfg.rope_theta if kind == ATTN_GLOBAL else getattr(
+            cfg, "rope_theta_local", cfg.rope_theta)
+        pos = start_pos + jnp.arange(S)
+        q = apply_rope(q, pos, theta)
+        k = apply_rope(k, pos, theta)
     new_cache = {"k": k, "v": v}
-
-    window = cfg.window if kind == ATTN_LOCAL else 0
-    if prefix_kv is not None:
-        P = prefix_kv["k"].shape[1]
-        pstart = (start_pos - P) if prefix_start is None else prefix_start
-        kv_pos = jnp.concatenate([pstart + jnp.arange(P), pos])
-        k_all = jnp.concatenate(
-            [_repeat_kv(prefix_kv["k"], cfg.n_heads),
-             _repeat_kv(k, cfg.n_heads)], axis=1)
-        v_all = jnp.concatenate(
-            [_repeat_kv(prefix_kv["v"], cfg.n_heads),
-             _repeat_kv(v, cfg.n_heads)], axis=1)
-        kv_valid = None
-        if kv_lens is not None:
-            # padding lives only in the prefix region; new tokens are valid
-            kv_valid = jnp.concatenate(
-                [jnp.arange(P)[None, :] < kv_lens[:, None],
-                 jnp.ones((x.shape[0], S), bool)], axis=1)
-        out = online_attention(q, k_all, v_all, pos, kv_pos, causal=True,
-                               window=window, kv_valid=kv_valid)
-    else:
-        kf = _repeat_kv(k, cfg.n_heads)
-        vf = _repeat_kv(v, cfg.n_heads)
-        use_pallas = (attention_impl == "pallas" and kv_lens is None
-                      and window == 0
-                      and (S <= 128 or S % 128 == 0))
-        if use_pallas:
-            from repro.kernels import ops
-            out = ops.prefill_attention(q, kf, vf, window=0, impl="pallas")
-        elif cfg.flash_vjp and kv_lens is None and not cfg.attn_block_full:
-            out = flash_attention(q, kf, vf, start_pos, start_pos, True,
-                                  window, 512)
-        elif kind == ATTN_LOCAL and cfg.window and not cfg.attn_block_full:
-            out = local_attention(q, kf, vf, start_pos, cfg.window)
+    with jax.named_scope("core"):
+        window = cfg.window if kind == ATTN_LOCAL else 0
+        if prefix_kv is not None:
+            P = prefix_kv["k"].shape[1]
+            pstart = (start_pos - P) if prefix_start is None else prefix_start
+            kv_pos = jnp.concatenate([pstart + jnp.arange(P), pos])
+            k_all = jnp.concatenate(
+                [_repeat_kv(prefix_kv["k"], cfg.n_heads),
+                 _repeat_kv(k, cfg.n_heads)], axis=1)
+            v_all = jnp.concatenate(
+                [_repeat_kv(prefix_kv["v"], cfg.n_heads),
+                 _repeat_kv(v, cfg.n_heads)], axis=1)
+            kv_valid = None
+            if kv_lens is not None:
+                # padding lives only in the prefix region; new tokens are
+                # valid
+                kv_valid = jnp.concatenate(
+                    [jnp.arange(P)[None, :] < kv_lens[:, None],
+                     jnp.ones((x.shape[0], S), bool)], axis=1)
+            out = online_attention(q, k_all, v_all, pos, kv_pos, causal=True,
+                                   window=window, kv_valid=kv_valid)
         else:
-            kv_pos = start_pos + jnp.arange(S)
-            ch = (1 << 30) if cfg.attn_block_full else 256
-            out = online_attention(q, kf, vf, pos, kv_pos, causal=True,
-                                   window=window,
-                                   kv_lens=kv_lens, q_chunk=ch, kv_chunk=ch)
-    out = out.reshape(B, S, cfg.n_heads * cfg.head_dim)
-    return out @ params["wo"], new_cache
+            kf = _repeat_kv(k, cfg.n_heads)
+            vf = _repeat_kv(v, cfg.n_heads)
+            use_pallas = (attention_impl == "pallas" and kv_lens is None
+                          and window == 0
+                          and (S <= 128 or S % 128 == 0))
+            if use_pallas:
+                from repro.kernels import ops
+                out = ops.prefill_attention(q, kf, vf, window=0,
+                                            impl="pallas")
+            elif (cfg.flash_vjp and kv_lens is None
+                  and not cfg.attn_block_full):
+                out = flash_attention(q, kf, vf, start_pos, start_pos, True,
+                                      window, 512)
+            elif (kind == ATTN_LOCAL and cfg.window
+                  and not cfg.attn_block_full):
+                out = local_attention(q, kf, vf, start_pos, cfg.window)
+            else:
+                kv_pos = start_pos + jnp.arange(S)
+                ch = (1 << 30) if cfg.attn_block_full else 256
+                out = online_attention(q, kf, vf, pos, kv_pos, causal=True,
+                                       window=window, kv_lens=kv_lens,
+                                       q_chunk=ch, kv_chunk=ch)
+    with jax.named_scope("out"):
+        out = out.reshape(B, S, cfg.n_heads * cfg.head_dim)
+        return out @ params["wo"], new_cache
 
 
 def _trim_ctx(leaf, ctx_limit: Optional[int]):
@@ -509,38 +515,42 @@ def gqa_decode(params, cfg: ModelConfig, kind: str, x1, position,
     native on TPU, interpret-mode elsewhere); cases the kernel does not
     cover (no kv_lens, sliding window, non-block-multiple trimmed length)
     fall back to the jnp two-branch combine. Returns (out, new_kv)."""
-    q, k, v = _proj_qkv(params, cfg, x1)
-    theta = cfg.rope_theta if kind == ATTN_GLOBAL else getattr(
-        cfg, "rope_theta_local", cfg.rope_theta)
-    q = rope_single(q, position, theta)
-    k = rope_single(k, position, theta)
-    window = cfg.window if kind == ATTN_LOCAL else 0
-    k_c = dequantize_kv(_trim_ctx(cache["k"], ctx_limit), cfg)
-    v_c = dequantize_kv(_trim_ctx(cache["v"], ctx_limit), cfg)
-    S = k_c.shape[1]
-    use_pallas = (attention_impl == "pallas" and kv_lens is not None
-                  and window == 0 and (S <= 256 or S % 256 == 0))
-    if use_pallas:
-        from repro.kernels import ops
-        B = q.shape[0]
-        lens = jnp.asarray(kv_lens, jnp.int32)
-        # The kernel reads one contiguous buffer, so the fresh token's K/V
-        # is placed at each sequence's live length (engine callers guarantee
-        # kv_lens < the trimmed buffer length: the slot has append room).
-        # This stages a scattered copy of the trimmed read — the fetch-
-        # trimming happens inside the kernel grid, which never spans past
-        # max(lens)+1 when the caller also passes a tight ctx_limit.
-        idx = jnp.arange(B)
-        k_all = k_c.at[idx, lens].set(k[:, 0].astype(k_c.dtype))
-        v_all = v_c.at[idx, lens].set(v[:, 0].astype(v_c.dtype))
-        out = ops.decode_attention(q[:, 0], k_all, v_all, lens + 1,
-                                   impl="pallas")[:, None]
-    else:
-        out = decode_attention(q, k_c, v_c, k, v, kv_lens=kv_lens,
-                               window=window, pos=jnp.asarray(position))
-    out = out.reshape(x1.shape[0], 1, cfg.n_heads * cfg.head_dim)
-    return out @ params["wo"], {"k": quantize_kv(k, cfg),
-                                "v": quantize_kv(v, cfg)}
+    with jax.named_scope("qkv"):
+        q, k, v = _proj_qkv(params, cfg, x1)
+        theta = cfg.rope_theta if kind == ATTN_GLOBAL else getattr(
+            cfg, "rope_theta_local", cfg.rope_theta)
+        q = rope_single(q, position, theta)
+        k = rope_single(k, position, theta)
+    with jax.named_scope("core"):
+        window = cfg.window if kind == ATTN_LOCAL else 0
+        k_c = dequantize_kv(_trim_ctx(cache["k"], ctx_limit), cfg)
+        v_c = dequantize_kv(_trim_ctx(cache["v"], ctx_limit), cfg)
+        S = k_c.shape[1]
+        use_pallas = (attention_impl == "pallas" and kv_lens is not None
+                      and window == 0 and (S <= 256 or S % 256 == 0))
+        if use_pallas:
+            from repro.kernels import ops
+            B = q.shape[0]
+            lens = jnp.asarray(kv_lens, jnp.int32)
+            # The kernel reads one contiguous buffer, so the fresh token's
+            # K/V is placed at each sequence's live length (engine callers
+            # guarantee kv_lens < the trimmed buffer length: the slot has
+            # append room). This stages a scattered copy of the trimmed
+            # read — the fetch-trimming happens inside the kernel grid,
+            # which never spans past max(lens)+1 when the caller also
+            # passes a tight ctx_limit.
+            idx = jnp.arange(B)
+            k_all = k_c.at[idx, lens].set(k[:, 0].astype(k_c.dtype))
+            v_all = v_c.at[idx, lens].set(v[:, 0].astype(v_c.dtype))
+            out = ops.decode_attention(q[:, 0], k_all, v_all, lens + 1,
+                                       impl="pallas")[:, None]
+        else:
+            out = decode_attention(q, k_c, v_c, k, v, kv_lens=kv_lens,
+                                   window=window, pos=jnp.asarray(position))
+    with jax.named_scope("out"):
+        out = out.reshape(x1.shape[0], 1, cfg.n_heads * cfg.head_dim)
+        return out @ params["wo"], {"k": quantize_kv(k, cfg),
+                                    "v": quantize_kv(v, cfg)}
 
 
 # --------------------------------------------------------------------------- #

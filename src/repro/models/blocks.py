@@ -5,6 +5,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from .attention import (attn_skeleton, gqa_decode, gqa_prefill, mla_decode,
@@ -98,29 +99,36 @@ def block_prefill(params, cfg: ModelConfig, kind: str, x, start_pos,
     `attention_impl` (static) selects the prefill attention kernel for
     global-attention blocks; MLA, sliding-window and recurrent kinds have
     no Pallas prefill kernel and ignore it."""
-    h = apply_norm(params["ln1"], cfg, x)
     updates: Dict[str, Any] = {}
-    if kind == ATTN_MLA:
-        out, cache_out = mla_prefill(params["attn"], cfg, h, start_pos,
-                                     prefix_kv=cache, kv_lens=kv_lens,
-                                     prefix_start=prefix_start)
-    elif kind in (ATTN_GLOBAL, ATTN_LOCAL):
-        out, cache_out = gqa_prefill(params["attn"], cfg, kind, h, start_pos,
-                                     prefix_kv=cache, kv_lens=kv_lens,
-                                     prefix_start=prefix_start,
-                                     attention_impl=attention_impl)
-    elif kind == RWKV6:
-        state = cache or rwkv6_init_state(cfg, x.shape[0])
-        out, cache_out = rwkv6_prefill(params["tmix"], cfg, h,
-                                       {"s": state["s"], "shift": state["shift"]})
-    elif kind == RGLRU:
-        state = cache or rglru_init_state(cfg, x.shape[0])
-        out, cache_out = rglru_prefill(params["rglru"], cfg, h, state)
-    else:
-        raise ValueError(kind)
-    x = x + out
-    h2 = apply_norm(params["ln2"], cfg, x)
-    x = x + _ffn(params, cfg, kind, h2, cache, updates)
+    with jax.named_scope("norm"):
+        h = apply_norm(params["ln1"], cfg, x)
+    # "attn" holds the sequence mixer of every kind, residual included
+    with jax.named_scope("attn"):
+        if kind == ATTN_MLA:
+            out, cache_out = mla_prefill(params["attn"], cfg, h, start_pos,
+                                         prefix_kv=cache, kv_lens=kv_lens,
+                                         prefix_start=prefix_start)
+        elif kind in (ATTN_GLOBAL, ATTN_LOCAL):
+            out, cache_out = gqa_prefill(params["attn"], cfg, kind, h,
+                                         start_pos, prefix_kv=cache,
+                                         kv_lens=kv_lens,
+                                         prefix_start=prefix_start,
+                                         attention_impl=attention_impl)
+        elif kind == RWKV6:
+            state = cache or rwkv6_init_state(cfg, x.shape[0])
+            out, cache_out = rwkv6_prefill(
+                params["tmix"], cfg, h,
+                {"s": state["s"], "shift": state["shift"]})
+        elif kind == RGLRU:
+            state = cache or rglru_init_state(cfg, x.shape[0])
+            out, cache_out = rglru_prefill(params["rglru"], cfg, h, state)
+        else:
+            raise ValueError(kind)
+        x = x + out
+    with jax.named_scope("norm"):
+        h2 = apply_norm(params["ln2"], cfg, x)
+    with jax.named_scope("mlp"):
+        x = x + _ffn(params, cfg, kind, h2, cache, updates)
     cache_out = {**cache_out, **updates}
     return x, cache_out
 
@@ -134,25 +142,31 @@ def block_decode(params, cfg: ModelConfig, kind: str, x1, position,
     reads; recurrent state is fixed-size and unaffected. `attention_impl`
     (static) selects the GQA decode attention kernel; MLA and recurrent
     kinds have no Pallas decode kernel and ignore it."""
-    h = apply_norm(params["ln1"], cfg, x1)
     updates: Dict[str, Any] = {}
-    if kind == ATTN_MLA:
-        out, cache_out = mla_decode(params["attn"], cfg, h, position, cache,
-                                    kv_lens=kv_lens, ctx_limit=ctx_limit)
-    elif kind in (ATTN_GLOBAL, ATTN_LOCAL):
-        out, cache_out = gqa_decode(params["attn"], cfg, kind, h, position,
-                                    cache, kv_lens=kv_lens,
-                                    ctx_limit=ctx_limit,
-                                    attention_impl=attention_impl)
-    elif kind == RWKV6:
-        out, cache_out = rwkv6_decode(params["tmix"], cfg, h,
-                                      {"s": cache["s"], "shift": cache["shift"]})
-    elif kind == RGLRU:
-        out, cache_out = rglru_decode(params["rglru"], cfg, h, cache)
-    else:
-        raise ValueError(kind)
-    x1 = x1 + out
-    h2 = apply_norm(params["ln2"], cfg, x1)
-    x1 = x1 + _ffn(params, cfg, kind, h2, cache, updates)
+    with jax.named_scope("norm"):
+        h = apply_norm(params["ln1"], cfg, x1)
+    with jax.named_scope("attn"):
+        if kind == ATTN_MLA:
+            out, cache_out = mla_decode(params["attn"], cfg, h, position,
+                                        cache, kv_lens=kv_lens,
+                                        ctx_limit=ctx_limit)
+        elif kind in (ATTN_GLOBAL, ATTN_LOCAL):
+            out, cache_out = gqa_decode(params["attn"], cfg, kind, h, position,
+                                        cache, kv_lens=kv_lens,
+                                        ctx_limit=ctx_limit,
+                                        attention_impl=attention_impl)
+        elif kind == RWKV6:
+            out, cache_out = rwkv6_decode(
+                params["tmix"], cfg, h,
+                {"s": cache["s"], "shift": cache["shift"]})
+        elif kind == RGLRU:
+            out, cache_out = rglru_decode(params["rglru"], cfg, h, cache)
+        else:
+            raise ValueError(kind)
+        x1 = x1 + out
+    with jax.named_scope("norm"):
+        h2 = apply_norm(params["ln2"], cfg, x1)
+    with jax.named_scope("mlp"):
+        x1 = x1 + _ffn(params, cfg, kind, h2, cache, updates)
     cache_out = {**cache_out, **updates}
     return x1, cache_out

@@ -71,7 +71,8 @@ def lm_hidden(params, cfg: ModelConfig, tokens, *, mode: str = "train",
     global-attention blocks (see gqa_prefill); the train path keeps the
     default jnp attention."""
     pat, n_groups, rem = cfg.pattern_groups()
-    h, n_front = _embed_inputs(params, cfg, tokens, frontend_embeds)
+    with jax.named_scope("embed"):
+        h, n_front = _embed_inputs(params, cfg, tokens, frontend_embeds)
     sp = start_pos  # frontend tokens occupy the first positions
 
     def one_block(kind, bparams, hh, bcache):
@@ -102,39 +103,43 @@ def lm_hidden(params, cfg: ModelConfig, tokens, *, mode: str = "train",
     outer = remat and cfg.remat_granularity in ("group", "both")
     body = jax.checkpoint(group_fn) if outer else group_fn
     caches_out: Dict[str, Any] = {}
-    if n_groups:
-        gcaches = None if caches is None else caches["groups"]
-        if cfg.unroll_layers:
-            outs = []
-            for gi in range(n_groups):
-                gp = jax.tree_util.tree_map(lambda l: l[gi], params["groups"])
-                gc = None if gcaches is None else jax.tree_util.tree_map(
-                    lambda l: l[gi], gcaches)
-                h, o = body(h, (gp, gc))
-                outs.append(o)
-            gouts = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-        elif gcaches is None:
-            # scan can't carry a None xs leaf; close over it instead
-            def body_nocache(hc, gparams):
-                return body(hc, (gparams, None))
-            h, gouts = jax.lax.scan(body_nocache, h, params["groups"])
-        else:
-            h, gouts = jax.lax.scan(lambda hc, x: body(hc, x), h,
-                                    (params["groups"], gcaches))
-        caches_out["groups"] = gouts
-    if rem:
-        routs = {}
-        for i, kind in enumerate(rem):
-            key = f"p{i}"
-            rc = None if caches is None else caches["rem"][key]
-            h, co = block_prefill(params["rem"][key], cfg, kind, h, sp,
-                                  cache=rc, kv_lens=kv_lens,
-                                  prefix_start=prefix_start,
-                                  attention_impl=attention_impl)
-            if not train_mode:
-                routs[key] = co
-        caches_out["rem"] = routs
-    h = apply_norm(params["final_norm"], cfg, h)
+    with jax.named_scope("layers"):
+        if n_groups:
+            gcaches = None if caches is None else caches["groups"]
+            if cfg.unroll_layers:
+                outs = []
+                for gi in range(n_groups):
+                    gp = jax.tree_util.tree_map(lambda l: l[gi],
+                                                params["groups"])
+                    gc = None if gcaches is None else jax.tree_util.tree_map(
+                        lambda l: l[gi], gcaches)
+                    h, o = body(h, (gp, gc))
+                    outs.append(o)
+                gouts = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                               *outs)
+            elif gcaches is None:
+                # scan can't carry a None xs leaf; close over it instead
+                def body_nocache(hc, gparams):
+                    return body(hc, (gparams, None))
+                h, gouts = jax.lax.scan(body_nocache, h, params["groups"])
+            else:
+                h, gouts = jax.lax.scan(lambda hc, x: body(hc, x), h,
+                                        (params["groups"], gcaches))
+            caches_out["groups"] = gouts
+        if rem:
+            routs = {}
+            for i, kind in enumerate(rem):
+                key = f"p{i}"
+                rc = None if caches is None else caches["rem"][key]
+                h, co = block_prefill(params["rem"][key], cfg, kind, h, sp,
+                                      cache=rc, kv_lens=kv_lens,
+                                      prefix_start=prefix_start,
+                                      attention_impl=attention_impl)
+                if not train_mode:
+                    routs[key] = co
+            caches_out["rem"] = routs
+    with jax.named_scope("norm"):
+        h = apply_norm(params["final_norm"], cfg, h)
     if n_front:
         h = h[:, n_front:]
     return h, caches_out
@@ -156,16 +161,18 @@ def lm_prefill(params, cfg: ModelConfig, tokens, *, caches=None,
                               frontend_embeds=frontend_embeds, kv_lens=kv_lens,
                               prefix_start=prefix_start,
                               attention_impl=attention_impl)
-    if logits_at is None:
-        hh = h[:, -1]
-    else:
-        idx = jnp.asarray(logits_at, jnp.int32)
-        if idx.ndim == 0:
-            hh = jax.lax.dynamic_index_in_dim(h, idx, axis=1, keepdims=False)
-        else:  # per-sequence gather
-            hh = jnp.take_along_axis(
-                h, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return lm_logits(params, cfg, hh), caches_out
+    with jax.named_scope("logits"):
+        if logits_at is None:
+            hh = h[:, -1]
+        else:
+            idx = jnp.asarray(logits_at, jnp.int32)
+            if idx.ndim == 0:
+                hh = jax.lax.dynamic_index_in_dim(h, idx, axis=1,
+                                                  keepdims=False)
+            else:  # per-sequence gather
+                hh = jnp.take_along_axis(
+                    h, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        return lm_logits(params, cfg, hh), caches_out
 
 
 def lm_decode(params, cfg: ModelConfig, token, caches, position,
@@ -176,44 +183,53 @@ def lm_decode(params, cfg: ModelConfig, token, caches, position,
     upper bound on kv_lens used to trim attention cache reads;
     `attention_impl` (static) selects the GQA decode attention kernel."""
     pat, n_groups, rem = cfg.pattern_groups()
-    h = embed(params["embed"], cfg, token[:, None]).astype(cfg.jnp_dtype)
+    with jax.named_scope("embed"):
+        h = embed(params["embed"], cfg, token[:, None]).astype(cfg.jnp_dtype)
 
     updates: Dict[str, Any] = {}
-    if n_groups:
-        def group_fn(hc, xs):
-            gparams, gcache = xs
-            hh = hc
-            outs = {}
-            for i, kind in enumerate(pat):
-                key = f"p{i}"
-                hh, up = block_decode(gparams[key], cfg, kind, hh, position,
-                                      gcache[key], kv_lens=kv_lens,
-                                      ctx_limit=ctx_limit,
-                                      attention_impl=attention_impl)
-                outs[key] = up
-            return hh, outs
+    with jax.named_scope("layers"):
+        if n_groups:
+            def group_fn(hc, xs):
+                gparams, gcache = xs
+                hh = hc
+                outs = {}
+                for i, kind in enumerate(pat):
+                    key = f"p{i}"
+                    hh, up = block_decode(gparams[key], cfg, kind, hh,
+                                          position, gcache[key],
+                                          kv_lens=kv_lens,
+                                          ctx_limit=ctx_limit,
+                                          attention_impl=attention_impl)
+                    outs[key] = up
+                return hh, outs
 
-        if cfg.unroll_layers:
-            outs = []
-            for gi in range(n_groups):
-                gp = jax.tree_util.tree_map(lambda l: l[gi], params["groups"])
-                gc = jax.tree_util.tree_map(lambda l: l[gi], caches["groups"])
-                h, o = group_fn(h, (gp, gc))
-                outs.append(o)
-            gups = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-        else:
-            h, gups = jax.lax.scan(group_fn, h,
-                                   (params["groups"], caches["groups"]))
-        updates["groups"] = gups
-    if rem:
-        rups = {}
-        for i, kind in enumerate(rem):
-            key = f"p{i}"
-            h, up = block_decode(params["rem"][key], cfg, kind, h, position,
-                                 caches["rem"][key], kv_lens=kv_lens,
-                                 ctx_limit=ctx_limit,
-                                 attention_impl=attention_impl)
-            rups[key] = up
-        updates["rem"] = rups
-    h = apply_norm(params["final_norm"], cfg, h)
-    return lm_logits(params, cfg, h[:, 0]), updates
+            if cfg.unroll_layers:
+                outs = []
+                for gi in range(n_groups):
+                    gp = jax.tree_util.tree_map(lambda l: l[gi],
+                                                params["groups"])
+                    gc = jax.tree_util.tree_map(lambda l: l[gi],
+                                                caches["groups"])
+                    h, o = group_fn(h, (gp, gc))
+                    outs.append(o)
+                gups = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                              *outs)
+            else:
+                h, gups = jax.lax.scan(group_fn, h,
+                                       (params["groups"], caches["groups"]))
+            updates["groups"] = gups
+        if rem:
+            rups = {}
+            for i, kind in enumerate(rem):
+                key = f"p{i}"
+                h, up = block_decode(params["rem"][key], cfg, kind, h,
+                                     position, caches["rem"][key],
+                                     kv_lens=kv_lens,
+                                     ctx_limit=ctx_limit,
+                                     attention_impl=attention_impl)
+                rups[key] = up
+            updates["rem"] = rups
+    with jax.named_scope("norm"):
+        h = apply_norm(params["final_norm"], cfg, h)
+    with jax.named_scope("logits"):
+        return lm_logits(params, cfg, h[:, 0]), updates

@@ -285,19 +285,40 @@ def test_server_fused_matches_reference_end_to_end(qwen):
                               replica_id=1)]
         srv = EngineServer(make_scheduler("conserve"), reps,
                            decode_mode=mode, record_tokens=True,
-                           strict_accounting=True)
+                           strict_accounting=True, tracing=True)
         recs = srv.serve(trace)
         srv.check_accounting()
         return srv, recs
 
     s_ref, r_ref = run("reference")
     s_fus, r_fus = run("fused")
-    assert s_ref.sampled_tokens == s_fus.sampled_tokens
+    assert s_ref.sampled_tokens == s_fus.sampled_tokens, \
+        _first_divergence(s_ref, s_fus)
     a = sorted((c.cid, t.turn_idx, t.n_output_tokens)
                for c in r_ref for t in c.turns)
     b = sorted((c.cid, t.turn_idx, t.n_output_tokens)
                for c in r_fus for t in c.turns)
     assert a == b
+
+
+def _first_divergence(ref, fus) -> str:
+    """The first (cid, turn) whose streams differ between two traced
+    servers, and each one's decode chunks in dispatch order as (node,
+    program, scan steps, ctx bucket, emitting lanes)."""
+    keys = sorted(set(ref.sampled_tokens) | set(fus.sampled_tokens))
+    bad = next((k for k in keys if ref.sampled_tokens.get(k)
+                != fus.sampled_tokens.get(k)), None)
+
+    def chunks(srv):
+        return [(r.attrs["node"], r.attrs["program"], r.attrs["n_steps"],
+                 r.attrs["ctx"], r.attrs["lanes"])
+                for r in sorted(srv.tracer.records, key=lambda r: r.t0)
+                if r.name == "engine.decode_chunk"]
+
+    return (f"first differing (cid, turn) {bad}: reference "
+            f"{ref.sampled_tokens.get(bad)}, fused "
+            f"{fus.sampled_tokens.get(bad)}; reference decode chunks "
+            f"{chunks(ref)}; fused decode chunks {chunks(fus)}")
 
 
 def _staggered_trace():
