@@ -45,10 +45,11 @@ def _write_slot(cache_leaf, new_leaf, slot: int, length: int, grouped: bool):
 def fold_decode_step(caches, updates, lens, mask, grouped, growing):
     """Pure, jit-safe fold of one decode step's cache updates: growing
     entries scatter at each slot's current length (dynamic ``.at[].set``),
-    fixed states replace where ``mask`` is set. This is the function the
-    fused donated decode step runs *inside* jit so XLA updates the cache
-    buffers in place; `SlotKVCache.append_step` below keeps the original
-    host-side copy path alive as the parity oracle.
+    fixed states replace where ``mask`` is set. The decode step of the
+    Pallas attention path (and of encoder-decoders) runs it *inside* jit
+    after its layer scan, so XLA updates the donated cache buffers in
+    place; the xla path writes the same rows inside the layer scan
+    (`repro.models.attention.write_rows`).
 
     ``mask`` is the per-step LIVE mask, not just slot activity: the ragged
     scan passes ``emit & (step < remaining)``, so a slot whose per-slot
@@ -155,9 +156,11 @@ class SlotKVCache:
         self.caches = model.init_cache(n_slots, max_ctx)
         self.lengths = np.zeros(n_slots, np.int32)
         self.active = np.zeros(n_slots, bool)
+        # every leaf under a layer-stacked subtree leads with the layer
+        # axis, a (layers, slots, width) recurrent state included
         self._grouped = jax.tree_util.tree_map_with_path(
-            lambda p, l: l.ndim >= 4 and str(
-                getattr(p[0], "key", p[0])) in ("groups", "self", "cross"),
+            lambda p, l: str(getattr(p[0], "key", p[0])) in (
+                "groups", "self", "cross"),
             self.caches)
         self._growing = jax.tree_util.tree_map_with_path(
             lambda p, l: _is_growing(p), self.caches)
@@ -202,9 +205,6 @@ class SlotKVCache:
     def kv_lens(self) -> jnp.ndarray:
         return jnp.asarray(self.lengths)
 
-    def positions(self) -> jnp.ndarray:
-        return jnp.asarray(self.lengths)
-
     # ----- writes ----------------------------------------------------------------
     def write_prefill(self, slot: int, new_caches, length: int,
                       state_slot_batch1: bool = True):
@@ -217,20 +217,6 @@ class SlotKVCache:
         self.caches = fold_prefill(self.caches, new_caches, slot, prev,
                                    self._grouped, self._growing)
         self.lengths[slot] = length
-
-    def append_step(self, updates, emitted_mask: np.ndarray):
-        """REFERENCE PATH: fold one decode step's cache updates in from the
-        host side — growing entries land at each slot's current length;
-        states replace. emitted_mask marks slots that actually decoded
-        (others keep their state). The serving hot path runs the same fold
-        *inside* the donated jit program (one dispatch per chunk, in-place);
-        this per-token host-side version is the dispatch/copy baseline for
-        parity tests and benchmarks — true math independence comes from the
-        model-rollout oracles in the tests, not from this path."""
-        self.caches = fold_decode_step(
-            self.caches, updates, jnp.asarray(self.lengths),
-            jnp.asarray(emitted_mask), self._grouped, self._growing)
-        self.lengths[emitted_mask] += 1
 
     # ----- transfer --------------------------------------------------------------
     @traced("engine.kv_export")

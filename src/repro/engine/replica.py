@@ -6,20 +6,24 @@ code paths pjit onto a mesh slice in production).
 Decode tail (the paper's memory-bound phase) is served by ONE jitted,
 buffer-donated program per (chunk, ctx) bucket: `jax.lax.scan` over the
 bucketed chunk length with on-device greedy sampling fed back as the next
-token and the per-slot cache scatter fused into the step
-(`fold_decode_step`), so XLA writes the donated KV buffers in place — no
-per-token full-cache copy, one dispatch + one host sync per chunk instead
-of per token. The scan is RAGGED: `decode_steps` takes a per-slot
-`remaining` vector and each slot freezes (stops folding KV, stops
-advancing its length, stops consuming tokens) once its own count is
-exhausted, so a nearly-finished turn no longer collapses the chunk for
-the whole batch — the agentic-trace irregularity the paper's
-conversation-level view is meant to absorb. Fused programs are AOT
+token and the per-slot cache write fused into the step, so XLA writes the
+donated KV buffers in place — no per-token full-cache copy, one dispatch +
+one host sync per chunk instead of per token. With xla attention each layer
+writes its row and reads only the live lanes' KV, up to the ctx bucket,
+straight from the layer-stacked cache (`Model.decode_lanes`); the Pallas
+path folds the step's new rows after the layer scan (`fold_decode_step`).
+The scan is RAGGED: `decode_steps` takes a per-slot `remaining` vector
+and each slot freezes (stops folding KV, stops advancing its length,
+stops consuming tokens) once its own count is exhausted, so a
+nearly-finished turn no longer collapses the chunk for the whole batch —
+the agentic-trace irregularity the paper's conversation-level view is
+meant to absorb. Fused programs are AOT
 compiled (`jax.jit(...).lower(...).compile()`): compile time accumulates
 in `compile_s` and never pollutes the measured per-chunk `dt` the server
 feeds its logical clock and TBT EMA. `decode_step_all_reference` keeps
-the original one-dispatch-per-token + host-side `append_step` copy path
-as the parity oracle and benchmark baseline.
+the one-dispatch-per-token path (host sync, host argmax, a copying cache
+write, no ctx trim) over the same step as the parity oracle and benchmark
+baseline.
 
 The (append-)prefill path (the paper's compute-bound phase, and the
 turn-2+ hot-prefix appends PPD treats as their own latency class) gets
@@ -190,10 +194,13 @@ class ReplicaEngine:
         # the engine-side ground truth behind NodeState.pooled_prefix_hits
         self.n_pooled_prefix_tokens = 0
 
+        # xla attention on a decoder-only stack decodes in place
+        # (Model.decode_lanes); the Pallas kernel and encoder-decoders
+        # return the step's new rows for fold_decode_step
+        self._in_place = (attention_impl == "xla"
+                          and not cfg.is_encoder_decoder)
         self._decode = jax.jit(
-            lambda p, t, c, pos, lens: self.model.decode_step(
-                p, t, c, pos, kv_lens=lens,
-                attention_impl=self.attention_impl))
+            lambda p, t, c, lens, live: self._step(p, t, c, lens, live, None))
         # fused donated decode programs, keyed by (scan length, ctx bucket)
         self._fused: Dict[Tuple[int, int], Any] = {}
         if warmup:
@@ -678,10 +685,37 @@ class ReplicaEngine:
         return self.sample(logits)[0], dt
 
     # ----- decode -----------------------------------------------------------------
+    def _step(self, params, tokens, caches, lens, live,
+              ctx_limit: Optional[int]):
+        """One decode step of every slot with its cache writes: the
+        arithmetic both decode modes share. Slot s decodes at position
+        lens[s]; a slot that is not `live` keeps its cache row, state and
+        length byte for byte. `ctx_limit` (static, or None for the whole
+        buffer) bounds the cache read. Returns (logits, caches)."""
+        if self._in_place:
+            return self.model.decode_lanes(params, tokens, caches, lens, live,
+                                           ctx_limit=ctx_limit)
+        logits, updates = self.model.decode_step(
+            params, tokens, caches, lens, kv_lens=lens, ctx_limit=ctx_limit,
+            attention_impl=self.attention_impl)
+        with jax.named_scope("kv_fold"):
+            return logits, fold_decode_step(caches, updates, lens, live,
+                                            self.kv._grouped,
+                                            self.kv._growing)
+
+    def _kv_read(self, n_steps: int, live: int, ctx: int) -> int:
+        """KV positions per layer a decode call reads: each live lane-step's
+        first `ctx` positions in place, or every slot's whole buffer at
+        every step where the layer scan slices whole slabs (Pallas,
+        encoder-decoder)."""
+        if self._in_place:
+            return live * ctx
+        return n_steps * self.kv.n_slots * self.kv.max_ctx
+
     def _build_fused(self, n_steps: int, ctx_limit: Optional[int]):
         """Fused decode program: scan over `n_steps` iterations with
         on-device greedy sampling fed back as the next token and the
-        per-slot cache scatter fused into the step. The cache pytree is
+        per-slot cache write fused into the step (`_step`). The cache pytree is
         DONATED — XLA aliases the input buffers into the outputs, so the
         decode tail appends in place instead of copying every leaf per
         token. The scan is ragged: `remaining` is a per-slot step count and
@@ -689,23 +723,17 @@ class ReplicaEngine:
         folding, its length stops advancing, its fed-back token freezes),
         so one compiled bucket serves any mix of per-slot chunk lengths up
         to n_steps."""
-        grouped, growing = self.kv._grouped, self.kv._growing
         vocab = self.cfg.vocab_size
 
         def run(params, caches, tokens, lens, emit, remaining):
             def body(carry, i):
                 caches, lens, tokens = carry
-                logits, updates = self.model.decode_step(
-                    params, tokens, caches, lens, kv_lens=lens,
-                    ctx_limit=ctx_limit,
-                    attention_impl=self.attention_impl)
+                live = emit & (i < remaining)
+                logits, caches = self._step(params, tokens, caches, lens,
+                                            live, ctx_limit)
                 with jax.named_scope("sample"):
                     sampled = jnp.argmax(logits[:, :vocab], axis=-1).astype(
                         jnp.int32)
-                live = emit & (i < remaining)
-                with jax.named_scope("kv_fold"):
-                    caches = fold_decode_step(caches, updates, lens, live,
-                                              grouped, growing)
                 lens = lens + live.astype(lens.dtype)
                 tokens = jnp.where(live, sampled, tokens)
                 return (caches, lens, tokens), sampled
@@ -840,7 +868,7 @@ class ReplicaEngine:
         sequence had been one dispatch schedule from the start. This is
         sound by construction, not by convention: each lane's math reads
         only its own slot's cache row and length, a frozen/inactive lane's
-        row is select-guarded to byte-identity (`fold_decode_step`), and
+        row is select-guarded to byte-identity (`_step`), and
         per-slot lengths advance by exactly the consumed share — so ANY
         partition of a turn's remaining tokens into chunk cuts, interleaved
         with other slots joining or finishing, yields byte-identical
@@ -874,7 +902,8 @@ class ReplicaEngine:
                           program=program_name("decode_fused", n_steps,
                                                ctx_limit),
                           n_steps=n_steps, ctx=ctx_limit,
-                          lanes=int(emit_mask.sum()), live=live)
+                          lanes=int(emit_mask.sum()), live=live,
+                          kv_read=self._kv_read(n_steps, live, ctx_limit))
         return seq, dt
 
     def decode_step_all(self, next_tokens: np.ndarray,
@@ -889,16 +918,19 @@ class ReplicaEngine:
                                   emit_mask: np.ndarray
                                   ) -> Tuple[np.ndarray, float]:
         """REFERENCE PATH (pre-fusion): one jitted dispatch + host sync +
-        host-side argmax per token, cache append via the copying
-        `append_step`. Kept as the parity oracle and benchmark baseline."""
+        host-side argmax per token over the fused scan's own step
+        (`_step`, the emitting slots live), reading the whole buffer and
+        writing a copy of the cache. Kept as the parity oracle and
+        benchmark baseline."""
         t0 = time.perf_counter()
-        lens = self.kv.kv_lens()
-        logits, updates = self._decode(
+        emit_mask = np.asarray(emit_mask, bool)
+        logits, caches = self._decode(
             self.params, jnp.asarray(next_tokens), self.kv.caches,
-            self.kv.positions(), lens)
+            self.kv.kv_lens(), jnp.asarray(emit_mask))
         with self.tracer.span("engine.device_wait"):
             logits = jax.block_until_ready(logits)
-        self.kv.append_step(updates, emit_mask)
+        self.kv.caches = caches
+        self.kv.lengths[emit_mask] += 1
         dt = time.perf_counter() - t0
         self.compute_s += dt
         self.decode_s += dt
@@ -906,5 +938,6 @@ class ReplicaEngine:
         self.n_decode_tokens += lanes
         self.tracer.timed(t0, dt, node=self.replica_id,
                           program="decode_reference", n_steps=1,
-                          ctx=self.kv.max_ctx, lanes=lanes, live=lanes)
+                          ctx=self.kv.max_ctx, lanes=lanes, live=lanes,
+                          kv_read=self._kv_read(1, lanes, self.kv.max_ctx))
         return self.sample(logits), dt

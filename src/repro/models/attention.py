@@ -9,8 +9,10 @@ Conventions
   the TP-sharded dimension).
 * Decode attention keeps Q replicated and shards the *KV length* dimension —
   context-parallel flash-decode, matching the memory-bound tail of the paper.
-* Decode never updates the big cache in-program: it returns the new token's
-  KV entries; the engine/cache-manager owns the append (see DESIGN.md §5).
+* `gqa_decode` returns the new token's KV entries and leaves the append to
+  the engine/cache-manager (see DESIGN.md §5). The engine's own decode step,
+  `gqa_decode_lanes`, writes that row into the layer-stacked cache itself
+  and reads only the live lanes' KV from it, in place.
 """
 from __future__ import annotations
 
@@ -361,6 +363,16 @@ def _partial_softmax(s, mask):
     return m, p.sum(-1), p
 
 
+def _combine(m_c, l_c, o_c, m_n, l_n, o_n):
+    """Merge the cache branch and the new token's branch of one-token
+    attention (flash combine of two partial softmaxes)."""
+    m = jnp.maximum(m_c, m_n)
+    c_c, c_n = jnp.exp(m_c - m), jnp.exp(m_n - m)
+    l = l_c * c_c + l_n * c_n
+    return (o_c * c_c[..., None] + o_n * c_n[..., None]) / jnp.maximum(
+        l[..., None], 1e-20)
+
+
 def decode_attention(q1, k_cache, v_cache, k_new, v_new, *,
                      kv_lens=None, window: int = 0, pos=None):
     """One-token GQA attention against cache + the freshly produced token.
@@ -393,12 +405,85 @@ def decode_attention(q1, k_cache, v_cache, k_new, v_new, *,
     m_n, l_n, p_n = _partial_softmax(s_n, jnp.ones_like(s_n, bool))
     o_n = jnp.einsum("bngl,blnd->bngd", p_n, v_new.astype(jnp.float32))
 
-    m = jnp.maximum(m_c, m_n)
-    c_c, c_n = jnp.exp(m_c - m), jnp.exp(m_n - m)
-    l = l_c * c_c + l_n * c_n
-    out = (o_c * c_c[..., None] + o_n * c_n[..., None]) / jnp.maximum(
-        l[..., None], 1e-20)
+    out = _combine(m_c, l_c, o_c, m_n, l_n, o_n)
     return out.reshape(B, 1, H, D).astype(q1.dtype)
+
+
+def write_rows(cache: Dict, rows: Dict, at: Tuple, lens, live) -> Dict:
+    """Select-guarded in-place write of one decode step's new entries: leaf
+    `cache[n]`, indexed down to (B, L, ...) by the leading indices `at`,
+    takes `rows[n][b, 0]` at (*at, b, lens[b]) for every live lane b. A lane
+    that is not live writes back its own bytes, so its row stays
+    byte-identical, as in the engine's `fold_decode_step`; an index past
+    the leaf's length (a windowed cache shorter than the context) is
+    dropped, as there."""
+    b = jnp.arange(live.shape[0])
+
+    def put(leaf, row):
+        idx = (*at, b, lens)
+        row = row[:, 0].astype(leaf.dtype)
+        keep = live.reshape((-1,) + (1,) * (row.ndim - 1))
+        return leaf.at[idx].set(jnp.where(keep, row, leaf[idx]))
+
+    return {**cache, **{n: put(cache[n], r) for n, r in rows.items()}}
+
+
+def lane_decode_attention(cfg: ModelConfig, q1, k_new, v_new, k_leaf, v_leaf,
+                          at: Tuple, lens, live, *,
+                          ctx_limit: Optional[int] = None, window: int = 0):
+    """One-token GQA attention that reads only the live lanes' KV, in place.
+
+    q1: (B, 1, H, D); new: (B, 1, Hkv, D); leaves: (*lead, B, L, Hkv, D),
+    indexed down to one layer by `at`. A loop whose trip count is the number
+    of live lanes reads each live lane's first `ctx_limit` positions
+    straight from the leaves as [C*Hkv, D] rows, a view of the stored
+    layout: the scores are those rows against every query head, of which
+    each head keeps its own KV head's; the probabilities, spread back over
+    the rows with zeros elsewhere (cast to the KV dtype, the precision of
+    the chip's default matmul), contract with the value rows. No slab
+    spanning several lanes is materialized. The same two-branch combine as
+    `decode_attention`; lanes that are not live get zeros."""
+    B, _, H, D = q1.shape
+    Hkv = k_leaf.shape[-2]
+    G = H // Hkv
+    L = k_leaf.shape[len(at) + 1]
+    C = L if ctx_limit is None else min(ctx_limit, L)
+    scale = 1.0 / math.sqrt(D)
+    own = (jnp.arange(H) // G)[None, :] == jnp.arange(Hkv)[:, None]  # Hkv, H
+    # the new token's branch for every lane: one score per head
+    s_n = jnp.einsum("bhd,bhd->bh", q1[:, 0], _repeat_kv(k_new, H)[:, 0],
+                     preferred_element_type=jnp.float32) * scale
+    o_n = _repeat_kv(v_new, H)[:, 0].astype(jnp.float32)
+    slots = jnp.nonzero(live, size=B, fill_value=0)[0]
+    idx = jnp.arange(C)
+
+    def lane(j, out):
+        b = slots[j]
+        start = (*at, b, 0, 0, 0)
+        size = (1,) * len(at) + (1, C, Hkv, D)
+        kr = dequantize_kv(jax.lax.dynamic_slice(k_leaf, start, size),
+                           cfg).reshape(C * Hkv, D)
+        vr = dequantize_kv(jax.lax.dynamic_slice(v_leaf, start, size),
+                           cfg).reshape(C * Hkv, D)
+        st = jnp.dot(kr, q1[b, 0].T, preferred_element_type=jnp.float32)
+        s = jnp.where(own[None], st.reshape(C, Hkv, H), 0).sum(1) * scale
+        mask = idx < lens[b]
+        if window:
+            mask &= idx > lens[b] - window
+        s = jnp.where(mask[:, None], s, NEG_INF)  # (C, H)
+        m_c = s.max(0)
+        p = jnp.exp(s - m_c[None])
+        l_c = p.sum(0)
+        pr = jnp.where(own[None], p[:, None, :], 0).astype(vr.dtype)
+        o_c = jax.lax.dot_general(pr.reshape(C * Hkv, H), vr,
+                                  (((0,), (0,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        o = _combine(m_c, l_c, o_c, s_n[b], jnp.ones_like(s_n[b]), o_n[b])
+        return jax.lax.dynamic_update_slice(out, o[None], (b, 0, 0))
+
+    out = jax.lax.fori_loop(0, live.sum(), lane,
+                            jnp.zeros((B, H, D), jnp.float32))
+    return out[:, None].astype(q1.dtype)
 
 
 # --------------------------------------------------------------------------- #
@@ -503,6 +588,34 @@ def _trim_ctx(leaf, ctx_limit: Optional[int]):
     if ctx_limit is None or leaf.shape[1] <= ctx_limit:
         return leaf
     return leaf[:, :ctx_limit]
+
+
+def gqa_decode_lanes(params, cfg: ModelConfig, kind: str, x1, cache: Dict,
+                     at: Tuple, lens, live, ctx_limit: Optional[int] = None):
+    """The engine's in-place GQA decode step (xla attention). cache: {"k","v"}
+    leaves (*lead, B, L, Hkv, hd) that `at` indexes down to this layer;
+    `lens` is each lane's length and position. The new token's K/V row is
+    written in place first (`write_rows`, select-guarded by `live`), then
+    attention reads only the live lanes' KV, up to `ctx_limit`
+    (`lane_decode_attention`). Returns (out, cache with the rows written)."""
+    with jax.named_scope("qkv"):
+        q, k, v = _proj_qkv(params, cfg, x1)
+        theta = cfg.rope_theta if kind == ATTN_GLOBAL else getattr(
+            cfg, "rope_theta_local", cfg.rope_theta)
+        q = rope_single(q, lens, theta)
+        k = rope_single(k, lens, theta)
+    with jax.named_scope("core"):
+        with jax.named_scope("kv_fold"):
+            cache = write_rows(cache, {"k": quantize_kv(k, cfg),
+                                       "v": quantize_kv(v, cfg)},
+                               at, lens, live)
+        out = lane_decode_attention(
+            cfg, q, k, v, cache["k"], cache["v"], at, lens, live,
+            ctx_limit=ctx_limit,
+            window=cfg.window if kind == ATTN_LOCAL else 0)
+    with jax.named_scope("out"):
+        out = out.reshape(x1.shape[0], 1, cfg.n_heads * cfg.head_dim)
+        return out @ params["wo"], cache
 
 
 def gqa_decode(params, cfg: ModelConfig, kind: str, x1, position,

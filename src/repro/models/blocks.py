@@ -8,8 +8,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .attention import (attn_skeleton, gqa_decode, gqa_prefill, mla_decode,
-                        mla_prefill)
+from .attention import (attn_skeleton, gqa_decode, gqa_decode_lanes,
+                        gqa_prefill, mla_decode, mla_prefill, write_rows)
 from .config import (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA, RGLRU, RWKV6,
                      ModelConfig)
 from .layers import apply_mlp, apply_norm, mlp_skeleton, norm_skeleton, sds
@@ -155,14 +155,8 @@ def block_decode(params, cfg: ModelConfig, kind: str, x1, position,
                                         cache, kv_lens=kv_lens,
                                         ctx_limit=ctx_limit,
                                         attention_impl=attention_impl)
-        elif kind == RWKV6:
-            out, cache_out = rwkv6_decode(
-                params["tmix"], cfg, h,
-                {"s": cache["s"], "shift": cache["shift"]})
-        elif kind == RGLRU:
-            out, cache_out = rglru_decode(params["rglru"], cfg, h, cache)
         else:
-            raise ValueError(kind)
+            out, cache_out = _recurrent_decode(params, cfg, kind, h, cache)
         x1 = x1 + out
     with jax.named_scope("norm"):
         h2 = apply_norm(params["ln2"], cfg, x1)
@@ -170,3 +164,50 @@ def block_decode(params, cfg: ModelConfig, kind: str, x1, position,
         x1 = x1 + _ffn(params, cfg, kind, h2, cache, updates)
     cache_out = {**cache_out, **updates}
     return x1, cache_out
+
+
+def _recurrent_decode(params, cfg: ModelConfig, kind: str, h, state: Dict):
+    if kind == RWKV6:
+        return rwkv6_decode(params["tmix"], cfg, h,
+                            {"s": state["s"], "shift": state["shift"]})
+    if kind == RGLRU:
+        return rglru_decode(params["rglru"], cfg, h, state)
+    raise ValueError(kind)
+
+
+def block_decode_lanes(params, cfg: ModelConfig, kind: str, x1, cache: Dict,
+                       at: Tuple, lens, live, ctx_limit: Optional[int] = None
+                       ) -> Tuple[jnp.ndarray, Dict]:
+    """One layer of the engine's in-place decode step (xla attention).
+    `cache` holds the layer's growing leaves layer-stacked, indexed down to
+    this layer by `at`, and its fixed-size states as this layer's slice.
+    Growing leaves take the new token's row in place at (*at, slot,
+    lens[slot]); global and local GQA read only the live lanes' KV up to
+    `ctx_limit`, MLA its batched trimmed read of this layer. Fixed states
+    come back replaced for live lanes. A lane that is not live keeps its
+    rows and states byte for byte. Returns (x_out, cache_out)."""
+    updates: Dict[str, Any] = {}
+    with jax.named_scope("norm"):
+        h = apply_norm(params["ln1"], cfg, x1)
+    with jax.named_scope("attn"):
+        if kind in (ATTN_GLOBAL, ATTN_LOCAL):
+            out, cache_out = gqa_decode_lanes(params["attn"], cfg, kind, h,
+                                              cache, at, lens, live,
+                                              ctx_limit=ctx_limit)
+        elif kind == ATTN_MLA:
+            out, rows = mla_decode(params["attn"], cfg, h, lens,
+                                   {n: l[at] for n, l in cache.items()},
+                                   kv_lens=lens, ctx_limit=ctx_limit)
+            cache_out = write_rows(cache, rows, at, lens, live)
+        else:
+            out, cache_out = _recurrent_decode(params, cfg, kind, h, cache)
+        x1 = x1 + out
+    with jax.named_scope("norm"):
+        h2 = apply_norm(params["ln2"], cfg, x1)
+    with jax.named_scope("mlp"):
+        x1 = x1 + _ffn(params, cfg, kind, h2, cache, updates)
+    states = {n: jnp.where(live.reshape((-1,) + (1,) * (u.ndim - 1)),
+                           u.astype(cache[n].dtype), cache[n])
+              for n, u in {**cache_out, **updates}.items()
+              if n not in GROWING_KEYS}
+    return x1, {**cache_out, **states}

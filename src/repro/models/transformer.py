@@ -9,7 +9,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .blocks import block_cache_skeleton, block_decode, block_prefill, block_skeleton
+from .blocks import (GROWING_KEYS, block_cache_skeleton, block_decode,
+                     block_decode_lanes, block_prefill, block_skeleton)
 from .config import ModelConfig
 from .layers import (apply_norm, embed, embed_skeleton, norm_skeleton, sds,
                      unembed, unembed_skeleton)
@@ -233,3 +234,62 @@ def lm_decode(params, cfg: ModelConfig, token, caches, position,
         h = apply_norm(params["final_norm"], cfg, h)
     with jax.named_scope("logits"):
         return lm_logits(params, cfg, h[:, 0]), updates
+
+
+def _split_growing(leaves: Dict[str, Any]):
+    """One block's cache -> (its growing leaves, its fixed states)."""
+    return ({n: l for n, l in leaves.items() if n in GROWING_KEYS},
+            {n: l for n, l in leaves.items() if n not in GROWING_KEYS})
+
+
+def lm_decode_lanes(params, cfg: ModelConfig, token, caches, lens, live,
+                    ctx_limit=None):
+    """The engine's in-place decode step (xla attention): one token for every
+    lane, its KV written into `caches` inside the layer scan, attention
+    reading only the `live` lanes' KV up to `ctx_limit` (static) straight
+    from the layer-stacked leaves. The scan carries the growing leaves
+    instead of slicing them as its xs, so no layer's slab spanning several
+    lanes is ever materialized; fixed-size states are sliced per layer as
+    in `lm_decode`. `lens` (B,) is each lane's length and position. A lane
+    that is not live keeps its cache rows and states byte for byte (its
+    logits are computed from a zero attention output and mean nothing).
+    The layer loop is always a scan (`cfg.unroll_layers` serves the dry
+    run's cost analysis of `lm_decode`). Returns (logits (B,V), caches)."""
+    pat, n_groups, rem = cfg.pattern_groups()
+    with jax.named_scope("embed"):
+        h = embed(params["embed"], cfg, token[:, None]).astype(cfg.jnp_dtype)
+
+    out: Dict[str, Any] = {}
+    with jax.named_scope("layers"):
+        if n_groups:
+            def group_fn(carry, xs):
+                hh, grow = carry
+                gparams, fixed, layer = xs
+                grow, fixed = dict(grow), dict(fixed)
+                for i, kind in enumerate(pat):
+                    key = f"p{i}"
+                    hh, c = block_decode_lanes(
+                        gparams[key], cfg, kind, hh,
+                        {**grow[key], **fixed[key]}, (layer,), lens, live,
+                        ctx_limit=ctx_limit)
+                    grow[key], fixed[key] = _split_growing(c)
+                return (hh, grow), fixed
+
+            split = {k: _split_growing(c) for k, c in caches["groups"].items()}
+            grow = {k: g for k, (g, _) in split.items()}
+            fixed = {k: f for k, (_, f) in split.items()}
+            (h, grow), fixed = jax.lax.scan(
+                group_fn, (h, grow),
+                (params["groups"], fixed, jnp.arange(n_groups)))
+            out["groups"] = {k: {**grow[k], **fixed[k]} for k in grow}
+        if rem:
+            out["rem"] = {}
+            for i, kind in enumerate(rem):
+                key = f"p{i}"
+                h, out["rem"][key] = block_decode_lanes(
+                    params["rem"][key], cfg, kind, h, caches["rem"][key], (),
+                    lens, live, ctx_limit=ctx_limit)
+    with jax.named_scope("norm"):
+        h = apply_norm(params["final_norm"], cfg, h)
+    with jax.named_scope("logits"):
+        return lm_logits(params, cfg, h[:, 0]), out

@@ -1,8 +1,11 @@
 """Decode-tail rebuild tests: the fused donated in-place decode step vs the
-retained `append_step` reference path, the multi-token RAGGED scan loop
-(per-slot remaining, mid-chunk freezes), the length-trimmed flash-decode
+retained per-token reference path, the multi-token RAGGED scan loop
+(per-slot remaining, mid-chunk freezes), the in-place live-lane KV read
+against the batched read it replaced, the length-trimmed flash-decode
 grid, ctx-trimmed model decode, and end-to-end EngineServer equivalence
 between decode modes including staggered-finish agentic traces."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +14,9 @@ import pytest
 from repro.configs import get_reduced
 from repro.core import make_scheduler
 from repro.core.conversation import Conversation, Turn
+from repro.core.tracing import Tracer
 from repro.engine import EngineServer, ReplicaEngine
+from repro.engine.kvcache import SlotKVCache, fold_decode_step
 from repro.kernels import ref
 from repro.kernels.decode_attention import flash_decode_attention
 from repro.models import build_model
@@ -39,7 +44,7 @@ def _prefill_two(cfg, params, n_slots=4, max_ctx=256):
 
 
 # --------------------------------------------------------------------------- #
-# fused in-place decode vs the retained append_step reference path
+# fused in-place decode vs the retained per-token reference path
 # --------------------------------------------------------------------------- #
 def test_fused_decode_matches_reference_tokens_and_cache(qwen):
     cfg, model, params = qwen
@@ -203,6 +208,107 @@ def test_warmup_precompiles_and_separates_compile_time(qwen):
     _, dt_cold = eng.decode_steps(nt, em, 2)
     assert eng.compile_s > before
     assert dt_cold < 100 * max(dt, 1e-4)
+
+
+# --------------------------------------------------------------------------- #
+# in-place live-lane KV read vs the batched read + post-scan fold
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("arch,kv_heads,ctx_limit", [
+    ("qwen3-0.6b", 4, 64),     # G = 1, ctx bucket below max_ctx
+    ("qwen3-0.6b", 2, 128),    # G = 2, ctx bucket at max_ctx
+    ("qwen3-0.6b", 2, 64),     # G = 2, ctx bucket below max_ctx
+    ("gemma3-12b", 4, 128),    # sliding-window layers beside a global one
+    ("recurrentgemma-9b", 1, 128),  # recurrent states beside a window, G = 4
+    ("deepseek-v2-lite-16b", 4, 64),  # MLA keeps its batched trimmed read
+])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_decode_lanes_matches_batched_read(arch, kv_heads, ctx_limit, seed):
+    """`Model.decode_lanes` (rows written inside the layer scan, attention
+    over the live lanes only) against `decode_step` + `fold_decode_step`
+    (every lane's slab read, rows folded after the scan) over a ragged
+    chunk: a random live mask, random per-lane remaining, a lane whose
+    length + n_steps equals ctx_limit exactly. Live logits agree to float32
+    rounding (1e-4, as the ctx-trim test below: the sums run in another
+    order through every layer), greedy tokens are equal, caches agree, and
+    every position a lane did not write keeps its bytes."""
+    cfg = dataclasses.replace(get_reduced(arch), n_kv_heads=kv_heads)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(seed))
+    B, max_ctx, n_steps = 5, 128, 6
+    kv = SlotKVCache(model, B, max_ctx)
+    rng = np.random.default_rng(seed)
+    keys = iter(jax.random.split(jax.random.PRNGKey(100 + seed), 64))
+    caches0 = jax.tree_util.tree_map(
+        lambda l: jax.random.normal(next(keys), l.shape).astype(l.dtype),
+        kv.caches)
+    emit = rng.random(B) < 0.6
+    emit[0], emit[-1] = True, False
+    rem = np.where(emit, rng.integers(1, n_steps + 1, B), 0)
+    rem[0] = n_steps
+    lens0 = rng.integers(1, ctx_limit - n_steps + 1, B)
+    lens0[0] = ctx_limit - n_steps  # its last step writes position ctx-1
+
+    old_step = jax.jit(
+        lambda c, t, n, live: (lambda lg, up: (lg, fold_decode_step(
+            c, up, n, live, kv._grouped, kv._growing)))(
+            *model.decode_step(params, t, c, n, kv_lens=n,
+                               ctx_limit=ctx_limit)))
+    new_step = jax.jit(lambda c, t, n, live: model.decode_lanes(
+        params, t, c, n, live, ctx_limit=ctx_limit))
+    c_old = c_new = caches0
+    lens = jnp.asarray(lens0, jnp.int32)
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, B), jnp.int32)
+    for i in range(n_steps):
+        live = jnp.asarray(emit & (i < rem))
+        lg_old, c_old = old_step(c_old, tok, lens, live)
+        lg_new, c_new = new_step(c_new, tok, lens, live)
+        on = np.flatnonzero(np.asarray(live))
+        a = np.asarray(lg_old)[on, :cfg.vocab_size]
+        b = np.asarray(lg_new)[on, :cfg.vocab_size]
+        np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-4)
+        np.testing.assert_array_equal(b.argmax(-1), a.argmax(-1))
+        tok = jnp.where(live, jnp.argmax(lg_old[:, :cfg.vocab_size], -1),
+                        tok).astype(jnp.int32)
+        lens = lens + live.astype(jnp.int32)
+
+    written = np.zeros((B, max_ctx), bool)
+    for s_ in range(B):
+        written[s_, lens0[s_]:lens0[s_] + rem[s_]] = True
+    flat = jax.tree_util.tree_flatten_with_path
+    for (path, l0), l_old, l_new, g, gr in zip(
+            flat(caches0)[0], jax.tree_util.tree_leaves(c_old),
+            jax.tree_util.tree_leaves(c_new),
+            jax.tree_util.tree_leaves(kv._grouped),
+            jax.tree_util.tree_leaves(kv._growing)):
+        l0, l_old, l_new = map(np.asarray, (l0, l_old, l_new))
+        np.testing.assert_allclose(l_new, l_old, rtol=1e-5, atol=1e-5,
+                                   err_msg=str(path))
+        if g:  # one layer's view: (B, L, ...)
+            l0, l_new = l0[0], l_new[0]
+        if gr:  # positions no lane wrote keep their bytes
+            keep = ~written[:, :l0.shape[1]]
+            np.testing.assert_array_equal(l_new[keep], l0[keep],
+                                          err_msg=str(path))
+        else:  # fixed states of lanes that never emitted keep theirs
+            np.testing.assert_array_equal(l_new[~emit], l0[~emit],
+                                          err_msg=str(path))
+
+
+def test_decode_chunk_counts_kv_read(qwen):
+    """`engine.decode_chunk` reports `kv_read`, the KV positions the chunk
+    read per layer: the sum over steps of the live lanes times the ctx
+    bucket, on a ragged chunk."""
+    cfg, model, params = qwen
+    eng, (s0, s1), nt, em = _prefill_two(cfg, params)
+    tr = eng.tracer = Tracer(enabled=True)
+    rem = np.zeros(eng.kv.n_slots, np.int32)
+    rem[s0], rem[s1] = 3, 7
+    eng.decode_steps(nt, em, rem)
+    chunk = next(r for r in tr.records if r.name == "engine.decode_chunk")
+    live = int(sum((em & (i < rem)).sum() for i in range(8)))  # 8 steps
+    assert live == 10
+    assert chunk.attrs["ctx"] == 64
+    assert chunk.attrs["kv_read"] == live * chunk.attrs["ctx"]
 
 
 # --------------------------------------------------------------------------- #

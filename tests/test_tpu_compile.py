@@ -13,6 +13,7 @@ to the native path."""
 from __future__ import annotations
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -29,6 +30,9 @@ from repro.kernels.prefill_attention import flash_prefill_attention  # noqa: E40
 from repro.models.model import Model  # noqa: E402
 
 V5E_HBM_BYTES = 16 * 2**30
+# temporaries of the 8-slot, 2048-position, ctx-1024 decode program below
+# when its layer scan sliced each layer's whole KV slab for every slot
+WHOLE_SLAB_DECODE_TEMP_BYTES = 178_173_440
 
 
 @pytest.fixture(scope="module")
@@ -123,6 +127,26 @@ def test_qwen3_decode_step_compiles_for_v5e(one_chip, qwen3_engine, impl):
         vec(jnp.int32)).compile()
     _check_fits(compiled)
     assert _has_kernel(compiled) == (impl == "pallas")
+    if impl == "xla":
+        _check_reads_live_lanes_in_place(compiled)
+
+
+def _check_reads_live_lanes_in_place(compiled):
+    """The xla decode step reads each live lane's KV straight from the
+    layer-stacked cache: no instruction outputs one layer's slab spanning
+    the 8 slots at the ctx bucket or at the whole buffer, no copy outputs a
+    whole [28, 8, 2048, 8, 128] cache leaf, and the temporaries are no
+    larger than the whole-slab read's."""
+    text = compiled.as_text()
+    for n in (1024, 2048):
+        for shape in (rf"\w+\[1,8,{n},8,128\]", rf"\w+\[8,{n},8,128\]"):
+            hit = re.search(rf"^.*= {shape}\{{.*$", text, re.M)
+            assert hit is None, hit.group(0)[:300]
+    hit = re.search(r"^.*= \w+\[28,8,2048,8,128\]\{[^}]*\} copy\(.*$", text,
+                    re.M)
+    assert hit is None, hit.group(0)[:300]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= WHOLE_SLAB_DECODE_TEMP_BYTES, temp
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
