@@ -220,6 +220,8 @@ def test_warmup_precompiles_and_separates_compile_time(qwen):
     ("gemma3-12b", 4, 128),    # sliding-window layers beside a global one
     ("recurrentgemma-9b", 1, 128),  # recurrent states beside a window, G = 4
     ("deepseek-v2-lite-16b", 4, 64),  # MLA keeps its batched trimmed read
+    ("olmo-1b", 4, 128),       # MHA (G = 1), non-parametric LayerNorm,
+                               # ctx bucket at max_ctx
 ])
 @pytest.mark.parametrize("seed", [0, 1])
 def test_decode_lanes_matches_batched_read(arch, kv_heads, ctx_limit, seed):

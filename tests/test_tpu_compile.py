@@ -12,8 +12,10 @@ and the engine's Pallas programs are traced with `ops._interpret` steered
 to the native path."""
 from __future__ import annotations
 
+import json
 import os
 import re
+from pathlib import Path
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -91,17 +93,17 @@ def test_flash_prefill_compiles_for_v5e(one_chip, S):
 
 
 @pytest.fixture
-def qwen3_engine(monkeypatch):
-    """A full-width qwen3-0.6b replica holding shapes, not arrays: params
+def engine(monkeypatch):
+    """A full-width replica of `arch` holding shapes, not arrays: params
     are the model skeleton and the slot cache its cache skeleton, so the
     engine's own program builders trace at published widths without
-    allocating 1.2 GB of weights and the KV buffers."""
-    def factory(attention_impl: str, n_slots: int, max_ctx: int):
+    allocating the weights and the KV buffers."""
+    def factory(arch: str, attention_impl: str, n_slots: int, max_ctx: int):
         if attention_impl == "pallas":
             monkeypatch.setattr(ops, "_interpret", lambda: False)
         monkeypatch.setattr(Model, "init_cache",
                             lambda self, b, c: self.cache_skeleton(b, c))
-        cfg = get_config("qwen3-0.6b")
+        cfg = get_config(arch)
         return ReplicaEngine(cfg, Model(cfg).skeleton(), n_slots=n_slots,
                              max_ctx=max_ctx, attention_impl=attention_impl)
     return factory
@@ -115,10 +117,10 @@ def _check_fits(compiled):
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_qwen3_decode_step_compiles_for_v5e(one_chip, qwen3_engine, impl):
+def test_qwen3_decode_step_compiles_for_v5e(one_chip, engine, impl):
     """The fused donated decode program at 8 slots, cache 2048, ctx_limit
     1024: the program a decode replica dispatches per chunk."""
-    eng = qwen3_engine(impl, n_slots=8, max_ctx=2048)
+    eng = engine("qwen3-0.6b", impl, n_slots=8, max_ctx=2048)
     vec = lambda dt: jax.ShapeDtypeStruct((8,), dt,  # noqa: E731
                                           sharding=one_chip)
     compiled = eng._build_fused(8, 1024).lower(
@@ -128,32 +130,33 @@ def test_qwen3_decode_step_compiles_for_v5e(one_chip, qwen3_engine, impl):
     _check_fits(compiled)
     assert _has_kernel(compiled) == (impl == "pallas")
     if impl == "xla":
-        _check_reads_live_lanes_in_place(compiled)
+        _check_reads_live_lanes_in_place(compiled, eng, 1024)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp <= WHOLE_SLAB_DECODE_TEMP_BYTES, temp
 
 
-def _check_reads_live_lanes_in_place(compiled):
+def _check_reads_live_lanes_in_place(compiled, eng, ctx_limit: int):
     """The xla decode step reads each live lane's KV straight from the
     layer-stacked cache: no instruction outputs one layer's slab spanning
-    the 8 slots at the ctx bucket or at the whole buffer, no copy outputs a
-    whole [28, 8, 2048, 8, 128] cache leaf, and the temporaries are no
-    larger than the whole-slab read's."""
+    every slot at the ctx bucket or at the whole buffer, and no copy
+    outputs a whole [layers, slots, max_ctx, kv heads, head_dim] leaf."""
+    cfg, B, L = eng.cfg, eng.kv.n_slots, eng.kv.max_ctx
+    tail = f"{cfg.n_kv_heads},{cfg.head_dim}"
     text = compiled.as_text()
-    for n in (1024, 2048):
-        for shape in (rf"\w+\[1,8,{n},8,128\]", rf"\w+\[8,{n},8,128\]"):
+    for n in sorted({ctx_limit, L}):
+        for shape in (rf"\w+\[1,{B},{n},{tail}\]", rf"\w+\[{B},{n},{tail}\]"):
             hit = re.search(rf"^.*= {shape}\{{.*$", text, re.M)
             assert hit is None, hit.group(0)[:300]
-    hit = re.search(r"^.*= \w+\[28,8,2048,8,128\]\{[^}]*\} copy\(.*$", text,
-                    re.M)
+    hit = re.search(rf"^.*= \w+\[{cfg.n_layers},{B},{L},{tail}\]\{{[^}}]*\}} "
+                    rf"copy\(.*$", text, re.M)
     assert hit is None, hit.group(0)[:300]
-    temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp <= WHOLE_SLAB_DECODE_TEMP_BYTES, temp
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_qwen3_prefill_compiles_for_v5e(one_chip, qwen3_engine, impl):
+def test_qwen3_prefill_compiles_for_v5e(one_chip, engine, impl):
     """The turn-1 prefill program for the 512-token bucket, with its
     in-program KV write into the donated slot cache."""
-    eng = qwen3_engine(impl, n_slots=8, max_ctx=2048)
+    eng = engine("qwen3-0.6b", impl, n_slots=8, max_ctx=2048)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     compiled = eng._build_prefill().lower(
         _specs(eng.params, one_chip), _specs(eng.kv.caches, one_chip),
@@ -161,3 +164,61 @@ def test_qwen3_prefill_compiles_for_v5e(one_chip, qwen3_engine, impl):
         scalar, scalar, None).compile()
     _check_fits(compiled)
     assert _has_kernel(compiled) == (impl == "pallas")
+
+
+def _deployment(arch: str):
+    """(slots, max_ctx) of the benchmark's configuration for `arch`."""
+    root = Path(__file__).resolve().parents[1]
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    file = next(c["file"] for c in spec["configs"] if c["name"] == arch)
+    dep = json.loads((root / file).read_text())["deployment"]
+    return dep["slots"], dep["max_ctx"]
+
+
+def _check_deployment_fits(eng, compiled):
+    """The served deployment on one chip: the weights, three replicas' KV
+    (one prefiller, two decoders), this program's temporaries and one
+    cache leaf more for the eager KV import, under 16 GB (the benchmark's
+    sizing bound, below the chip's 16 GiB)."""
+    def nbytes(tree):
+        return [x.size * jnp.dtype(x.dtype).itemsize
+                for x in jax.tree_util.tree_leaves(tree)]
+    kv = nbytes(eng.kv.caches)
+    total = (sum(nbytes(eng.params)) + 3 * sum(kv)
+             + compiled.memory_analysis().temp_size_in_bytes + max(kv))
+    assert total < 16e9, total
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "olmo-1b"])
+def test_served_decode_fits_v5e(one_chip, engine, arch):
+    """The benchmark's deployment of `arch` at its published widths: the
+    fused decode program at (32 steps, max_ctx), the largest a decoder
+    dispatches, reads the live lanes in place and fits the chip beside the
+    deployment's weights and caches."""
+    slots, max_ctx = _deployment(arch)
+    eng = engine(arch, "xla", n_slots=slots, max_ctx=max_ctx)
+    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt,  # noqa: E731
+                                          sharding=one_chip)
+    compiled = eng._build_fused(32, max_ctx).lower(
+        _specs(eng.params, one_chip), _specs(eng.kv.caches, one_chip),
+        vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+        vec(jnp.int32)).compile()
+    _check_fits(compiled)
+    _check_reads_live_lanes_in_place(compiled, eng, max_ctx)
+    _check_deployment_fits(eng, compiled)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "olmo-1b"])
+def test_served_prefill_fits_v5e(one_chip, engine, arch):
+    """The turn-1 prefill program at the deployment's largest prompt bucket
+    (max_ctx), with its in-program KV write, fits the chip beside the
+    deployment's weights and caches."""
+    slots, max_ctx = _deployment(arch)
+    eng = engine(arch, "xla", n_slots=slots, max_ctx=max_ctx)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = eng._build_prefill().lower(
+        _specs(eng.params, one_chip), _specs(eng.kv.caches, one_chip),
+        jax.ShapeDtypeStruct((max_ctx,), jnp.int32, sharding=one_chip),
+        scalar, scalar, None).compile()
+    _check_fits(compiled)
+    _check_deployment_fits(eng, compiled)
