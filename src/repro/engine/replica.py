@@ -4,14 +4,18 @@ Runs real forward passes on whatever devices are visible (CPU here; the same
 code paths pjit onto a mesh slice in production).
 
 Decode tail (the paper's memory-bound phase) is served by ONE jitted,
-buffer-donated program per (chunk, ctx) bucket: `jax.lax.scan` over the
+buffer-donated program per chunk bucket, or per (chunk, ctx) bucket where
+the read needs a static context bound: `jax.lax.scan` over the
 bucketed chunk length with on-device greedy sampling fed back as the next
 token and the per-slot cache write fused into the step, so XLA writes the
 donated KV buffers in place — no per-token full-cache copy, one dispatch +
 one host sync per chunk instead of per token. With xla attention each layer
-writes its row and reads only the live lanes' KV, up to the ctx bucket,
-straight from the layer-stacked cache (`Model.decode_lanes`); the Pallas
-path folds the step's new rows after the layer scan (`fold_decode_step`).
+writes its row and reads only the live lanes' KV straight from the
+layer-stacked cache (`Model.decode_lanes`): on the TPU, where every layer
+takes the length-bounded Pallas read, each lane up to its own length, with
+no ctx bucket; elsewhere through the XLA lane loop up to the ctx bucket.
+`attention_impl="pallas"` folds the step's new rows after the layer scan
+(`fold_decode_step`).
 The scan is RAGGED: `decode_steps` takes a per-slot `remaining` vector
 and each slot freezes (stops folding KV, stops advancing its length,
 stops consuming tokens) once its own count is exhausted, so a
@@ -48,7 +52,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tracing import NULL_TRACER, traced
+from repro.kernels.lane_decode_attention import lane_block_k
 from repro.models import Model, build_model
+from repro.models.attention import lanes_length_bounded
 from repro.models.config import ModelConfig
 
 from .kvcache import (PrefixKVPool, SlotKVCache, fold_decode_step,
@@ -110,7 +116,8 @@ def program_name(kind: str, bucket: int, ctx: Optional[int] = None,
                  n_front: int = 0) -> str:
     """Stable name of one AOT program: `prefill_b<bucket>`,
     `append_b<bucket>_c<ctx>`, `shared_b<bucket>_c<ctx>`,
-    `decode_fused_n<steps>_c<ctx>`. The compiled module is `jit_<name>`, in
+    `decode_fused_n<steps>_c<ctx>` (`_cNone` where each lane's read is
+    bounded by its own length). The compiled module is `jit_<name>`, in
     its HLO text and in a device profile's "XLA Modules" line."""
     if kind == "decode_fused":
         return f"decode_fused_n{bucket}_c{ctx}"
@@ -199,10 +206,18 @@ class ReplicaEngine:
         # return the step's new rows for fold_decode_step
         self._in_place = (attention_impl == "xla"
                           and not cfg.is_encoder_decoder)
+        # ... and where every layer reads through the length-bounded Pallas
+        # kernel, the read needs no static ctx bound: programs are keyed by
+        # scan length alone (ctx None), and each lane-step reads its length
+        # rounded up to the kernel's block
+        self._lane_kernel = self._in_place and lanes_length_bounded(cfg)
+        self._lane_block = lane_block_k(
+            max_ctx, cfg.n_kv_heads, cfg.head_dim,
+            jnp.dtype(cfg.jnp_dtype).itemsize)
         self._decode = jax.jit(
             lambda p, t, c, lens, live: self._step(p, t, c, lens, live, None))
         # fused donated decode programs, keyed by (scan length, ctx bucket)
-        self._fused: Dict[Tuple[int, int], Any] = {}
+        self._fused: Dict[Tuple[int, Optional[int]], Any] = {}
         if warmup:
             self.warmup_decode()
             if self._prefill_jittable and prefill_mode == "jit":
@@ -703,13 +718,21 @@ class ReplicaEngine:
                                             self.kv._grouped,
                                             self.kv._growing)
 
-    def _kv_read(self, n_steps: int, live: int, ctx: int) -> int:
-        """KV positions per layer a decode call reads: each live lane-step's
-        first `ctx` positions in place, or every slot's whole buffer at
-        every step where the layer scan slices whole slabs (Pallas,
-        encoder-decoder)."""
+    def _kv_read(self, n_steps: int, lens: np.ndarray, rem: np.ndarray,
+                 ctx: Optional[int]) -> int:
+        """KV positions per layer a decode call reads, for lanes at lengths
+        `lens` taking `rem` steps each: each live lane-step's length rounded
+        up to the kernel's block (at least one block) where the read is
+        length-bounded; its first `ctx` positions through the XLA lane loop;
+        or every slot's whole buffer at every step where the layer scan
+        slices whole slabs (Pallas, encoder-decoder)."""
+        if self._lane_kernel:
+            bk = self._lane_block
+            step = np.arange(n_steps)
+            blocks = np.maximum(-(-(lens[:, None] + step) // bk), 1)
+            return int(bk * blocks[step < rem[:, None]].sum())
         if self._in_place:
-            return live * ctx
+            return int(np.sum(rem)) * ctx
         return n_steps * self.kv.n_slots * self.kv.max_ctx
 
     def _build_fused(self, n_steps: int, ctx_limit: Optional[int]):
@@ -746,11 +769,14 @@ class ReplicaEngine:
             _named(run, program_name("decode_fused", n_steps, ctx_limit)),
             donate_argnums=(1,))
 
-    def _get_fused(self, n_steps: int, ctx_limit: int):
+    def _get_fused(self, n_steps: int, ctx_limit: Optional[int]):
         """Fetch (or AOT-compile) the fused program for one (chunk, ctx)
-        bucket. Compile time goes to `self.compile_s`, NOT into any
+        bucket, or for the chunk alone (ctx None) where the read is
+        length-bounded. Compile time goes to `self.compile_s`, NOT into any
         measured decode dt — first bucket hits no longer pollute the
         server's logical clock or the observed TBT EMA."""
+        if self._lane_kernel:
+            ctx_limit = None
         key = (n_steps, ctx_limit)
         fn = self._fused.get(key)
         if fn is None:
@@ -772,8 +798,9 @@ class ReplicaEngine:
         """Pre-compile fused decode programs so serving never hits a cold
         (chunk, ctx) bucket. Defaults cover every bucket reachable on this
         replica: all DECODE_CHUNKS × all power-of-two ctx buckets up to
-        max_ctx. Returns the seconds spent compiling (also accumulated in
-        `self.compile_s`)."""
+        max_ctx; where the read is length-bounded, the chunks alone
+        (`_get_fused` drops the ctx). Returns the seconds spent compiling
+        (also accumulated in `self.compile_s`)."""
         if ctx_limits is None:
             ctx_limits = []
             b = CTX_BUCKET_MIN
@@ -783,7 +810,7 @@ class ReplicaEngine:
             ctx_limits.append(self.kv.max_ctx)
         before = self.compile_s
         for c in (chunks if chunks is not None else DECODE_CHUNKS):
-            for cl in dict.fromkeys(int(x) for x in ctx_limits):
+            for cl in dict.fromkeys(ctx_limits):
                 self._get_fused(decode_chunk_bucket(int(c)), cl)
         return self.compile_s - before
 
@@ -881,8 +908,10 @@ class ReplicaEngine:
         n_steps = decode_chunk_bucket(n_max)
         live_max = int(self.kv.lengths[emit_mask].max()) if emit_mask.any() \
             else 0
-        ctx_limit = ctx_bucket(live_max + n_steps, self.kv.max_ctx)
+        ctx_limit = None if self._lane_kernel else ctx_bucket(
+            live_max + n_steps, self.kv.max_ctx)
         fn = self._get_fused(n_steps, ctx_limit)
+        lens0 = self.kv.lengths[emit_mask].copy()
         t0 = time.perf_counter()
         caches, seq = fn(self.params, self.kv.caches,
                          jnp.asarray(next_tokens, jnp.int32),
@@ -903,7 +932,8 @@ class ReplicaEngine:
                                                ctx_limit),
                           n_steps=n_steps, ctx=ctx_limit,
                           lanes=int(emit_mask.sum()), live=live,
-                          kv_read=self._kv_read(n_steps, live, ctx_limit))
+                          kv_read=self._kv_read(n_steps, lens0,
+                                                rem[emit_mask], ctx_limit))
         return seq, dt
 
     def decode_step_all(self, next_tokens: np.ndarray,
@@ -924,6 +954,7 @@ class ReplicaEngine:
         benchmark baseline."""
         t0 = time.perf_counter()
         emit_mask = np.asarray(emit_mask, bool)
+        lens0 = self.kv.lengths[emit_mask].copy()
         logits, caches = self._decode(
             self.params, jnp.asarray(next_tokens), self.kv.caches,
             self.kv.kv_lens(), jnp.asarray(emit_mask))
@@ -936,8 +967,11 @@ class ReplicaEngine:
         self.decode_s += dt
         lanes = int(emit_mask.sum())
         self.n_decode_tokens += lanes
+        ctx = None if self._lane_kernel else self.kv.max_ctx
         self.tracer.timed(t0, dt, node=self.replica_id,
                           program="decode_reference", n_steps=1,
-                          ctx=self.kv.max_ctx, lanes=lanes, live=lanes,
-                          kv_read=self._kv_read(1, lanes, self.kv.max_ctx))
+                          ctx=ctx, lanes=lanes, live=lanes,
+                          kv_read=self._kv_read(1, lens0,
+                                                np.ones(lanes, np.int32),
+                                                ctx))
         return self.sample(logits), dt

@@ -4,7 +4,10 @@ execute in interpret mode (tests and validation); any other backend raises
 rather than interpreting in silence. `impl="xla"` selects the jnp
 reference path instead. The model substrate uses the XLA path for
 the multi-device dry-run (Pallas inside GSPMD is a per-backend concern);
-kernels are selectable via `attention_impl` for single-replica serving."""
+kernels are selectable via `attention_impl` for single-replica serving,
+and the engine's in-place decode reads the live lanes through
+`lane_decode_attention` wherever it runs on the TPU
+(`models.attention.lane_kernel_serves`)."""
 from __future__ import annotations
 
 from functools import partial
@@ -14,6 +17,7 @@ import jax.numpy as jnp
 
 from . import ref
 from .decode_attention import flash_decode_attention
+from .lane_decode_attention import lane_decode_attention as _lane_decode
 from .prefill_attention import flash_prefill_attention
 from .rglru_kernel import rglru_pallas
 from .rwkv6_kernel import wkv6_pallas
@@ -59,6 +63,16 @@ def decode_attention(q, k, v, lengths=None, *, impl: str = "pallas",
         return ref.decode_attention_ref(q, k, v, lengths)
     return flash_decode_attention(q, k, v, lengths, max_len=max_len,
                                   interpret=_interpret())
+
+
+def lane_decode_attention(q, k_new, v_new, k, v, layer, lens, live):
+    """One decode layer's attention over the live lanes' KV, read in place
+    from the layer-stacked leaves k, v (NL, B, L, Hkv, D) at `layer`, each
+    lane up to its own length; the new token's entries combine beside it.
+    Returns (B, H, D) float32, zeros for lanes that are not live. Called
+    inside the traced decode scan, so it is not jitted on its own."""
+    return _lane_decode(q, k_new, v_new, k, v, layer, lens, live,
+                        interpret=_interpret())
 
 
 @partial(jax.jit, static_argnames=("impl", "chunk"))

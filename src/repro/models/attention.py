@@ -590,14 +590,46 @@ def _trim_ctx(leaf, ctx_limit: Optional[int]):
     return leaf[:, :ctx_limit]
 
 
+def _kernel_platform() -> bool:
+    """Whether the Pallas kernels run natively here (the TPU)."""
+    return jax.default_backend() == "tpu"
+
+
+def lane_kernel_serves(cfg: ModelConfig, kind: str) -> bool:
+    """Whether a layer of `kind` reads the live lanes' KV through the
+    length-bounded Pallas kernel (`kernels/lane_decode_attention.py`)
+    rather than the XLA lane loop: on the TPU, for global GQA attention (no
+    window) over KV stored unquantized in bf16 or f32, with head_dim a lane
+    multiple and the KV heads filling whole 32-bit rows."""
+    if kind != ATTN_GLOBAL or not _kernel_platform():
+        return False
+    if cfg.kv_cache_dtype and cfg.kv_cache_dtype != cfg.dtype:
+        return False
+    dt = jnp.dtype(cfg.jnp_dtype)
+    return (dt in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+            and cfg.head_dim % 128 == 0
+            and cfg.n_kv_heads % (4 // dt.itemsize) == 0)
+
+
+def lanes_length_bounded(cfg: ModelConfig) -> bool:
+    """Whether every layer that reads a growing cache in the engine's
+    in-place decode step bounds its read by each lane's length (the Pallas
+    kernel), so the step needs no static context bound."""
+    reads = {k for k in cfg.block_pattern
+             if k in (ATTN_GLOBAL, ATTN_LOCAL, ATTN_MLA)}
+    return bool(reads) and all(lane_kernel_serves(cfg, k) for k in reads)
+
+
 def gqa_decode_lanes(params, cfg: ModelConfig, kind: str, x1, cache: Dict,
                      at: Tuple, lens, live, ctx_limit: Optional[int] = None):
-    """The engine's in-place GQA decode step (xla attention). cache: {"k","v"}
-    leaves (*lead, B, L, Hkv, hd) that `at` indexes down to this layer;
-    `lens` is each lane's length and position. The new token's K/V row is
-    written in place first (`write_rows`, select-guarded by `live`), then
-    attention reads only the live lanes' KV, up to `ctx_limit`
-    (`lane_decode_attention`). Returns (out, cache with the rows written)."""
+    """The engine's in-place GQA decode step. cache: {"k","v"} leaves
+    (*lead, B, L, Hkv, hd) that `at` indexes down to this layer; `lens` is
+    each lane's length and position. The new token's K/V row is written in
+    place first (`write_rows`, select-guarded by `live`), then attention
+    reads only the live lanes' KV: through the length-bounded Pallas kernel
+    where `lane_kernel_serves`, each lane up to its own length, else through
+    the XLA lane loop up to `ctx_limit` (`lane_decode_attention`). Returns
+    (out, cache with the rows written)."""
     with jax.named_scope("qkv"):
         q, k, v = _proj_qkv(params, cfg, x1)
         theta = cfg.rope_theta if kind == ATTN_GLOBAL else getattr(
@@ -609,10 +641,20 @@ def gqa_decode_lanes(params, cfg: ModelConfig, kind: str, x1, cache: Dict,
             cache = write_rows(cache, {"k": quantize_kv(k, cfg),
                                        "v": quantize_kv(v, cfg)},
                                at, lens, live)
-        out = lane_decode_attention(
-            cfg, q, k, v, cache["k"], cache["v"], at, lens, live,
-            ctx_limit=ctx_limit,
-            window=cfg.window if kind == ATTN_LOCAL else 0)
+        if lane_kernel_serves(cfg, kind):
+            from repro.kernels import ops
+            k_leaf, v_leaf = cache["k"], cache["v"]
+            if not at:  # an unstacked layer: a stack of one
+                k_leaf, v_leaf = k_leaf[None], v_leaf[None]
+            out = ops.lane_decode_attention(
+                q[:, 0], k[:, 0], v[:, 0], k_leaf, v_leaf,
+                at[0] if at else 0, lens, live)
+            out = out[:, None].astype(q.dtype)
+        else:
+            out = lane_decode_attention(
+                cfg, q, k, v, cache["k"], cache["v"], at, lens, live,
+                ctx_limit=ctx_limit,
+                window=cfg.window if kind == ATTN_LOCAL else 0)
     with jax.named_scope("out"):
         out = out.reshape(x1.shape[0], 1, cfg.n_heads * cfg.head_dim)
         return out @ params["wo"], cache

@@ -182,8 +182,9 @@ def block_decode_lanes(params, cfg: ModelConfig, kind: str, x1, cache: Dict,
     `cache` holds the layer's growing leaves layer-stacked, indexed down to
     this layer by `at`, and its fixed-size states as this layer's slice.
     Growing leaves take the new token's row in place at (*at, slot,
-    lens[slot]); global and local GQA read only the live lanes' KV up to
-    `ctx_limit`, MLA its batched trimmed read of this layer. Fixed states
+    lens[slot]); global and local GQA read only the live lanes' KV (up to
+    each lane's length through the lane kernel, else up to `ctx_limit`),
+    MLA its batched trimmed read of this layer. Fixed states
     come back replaced for live lanes. A lane that is not live keeps its
     rows and states byte for byte. Returns (x_out, cache_out)."""
     updates: Dict[str, Any] = {}

@@ -102,8 +102,9 @@ class Model:
     def decode_lanes(self, params, token, caches, lens, live, ctx_limit=None):
         """The serving engine's in-place decode step (decoder-only, xla
         attention): (logits (B,V), caches) with the new token's KV written
-        into `caches` for the `live` lanes, attention reading only their KV
-        up to `ctx_limit` (static). See `transformer.lm_decode_lanes`."""
+        into `caches` for the `live` lanes, attention reading only their KV:
+        each lane up to its own length where the lane kernel serves, else up
+        to `ctx_limit` (static). See `transformer.lm_decode_lanes`."""
         return transformer.lm_decode_lanes(params, self.cfg, token, caches,
                                            lens, live, ctx_limit=ctx_limit)
 

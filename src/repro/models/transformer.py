@@ -246,8 +246,10 @@ def lm_decode_lanes(params, cfg: ModelConfig, token, caches, lens, live,
                     ctx_limit=None):
     """The engine's in-place decode step (xla attention): one token for every
     lane, its KV written into `caches` inside the layer scan, attention
-    reading only the `live` lanes' KV up to `ctx_limit` (static) straight
-    from the layer-stacked leaves. The scan carries the growing leaves
+    reading only the `live` lanes' KV straight from the layer-stacked
+    leaves: each lane up to its own length where the lane kernel serves
+    (`attention.lane_kernel_serves`), else up to `ctx_limit` (static). The
+    scan carries the growing leaves
     instead of slicing them as its xs, so no layer's slab spanning several
     lanes is ever materialized; fixed-size states are sliced per layer as
     in `lm_decode`. `lens` (B,) is each lane's length and position. A lane

@@ -16,10 +16,12 @@ from repro.core import make_scheduler
 from repro.core.conversation import Conversation, Turn
 from repro.core.tracing import Tracer
 from repro.engine import EngineServer, ReplicaEngine
+from repro.engine.replica import DECODE_CHUNKS
 from repro.engine.kvcache import SlotKVCache, fold_decode_step
+from repro.kernels import lane_decode_attention as lane_kernel
 from repro.kernels import ref
 from repro.kernels.decode_attention import flash_decode_attention
-from repro.models import build_model
+from repro.models import attention, build_model
 from repro.traces import TraceConfig, generate_trace
 
 
@@ -311,6 +313,101 @@ def test_decode_chunk_counts_kv_read(qwen):
     assert live == 10
     assert chunk.attrs["ctx"] == 64
     assert chunk.attrs["kv_read"] == live * chunk.attrs["ctx"]
+
+
+# --------------------------------------------------------------------------- #
+# the length-bounded Pallas lane read, forced on by the platform check
+# --------------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def qwen_k128():
+    """The reduced qwen at a head width the lane kernel tiles (128) and two
+    query heads per KV head."""
+    cfg = dataclasses.replace(get_reduced("qwen3-0.6b"), head_dim=128,
+                              n_heads=8)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    return cfg, params
+
+
+def _kernel_engine(monkeypatch, cfg, params, **kw):
+    """A two-slot-prefilled replica whose decode takes the lane kernel
+    (interpreted on the CPU): the platform check says TPU."""
+    monkeypatch.setattr(attention, "_kernel_platform", lambda: True)
+    out = _prefill_two(cfg, params, **kw)
+    assert out[0]._lane_kernel
+    return out
+
+
+def test_lane_kernel_decode_matches_xla_path(monkeypatch, qwen_k128):
+    """A ragged fused chunk through the lane kernel gives the XLA lane
+    loop's tokens and lengths; written rows agree to f32 rounding, and every
+    cache position outside the written rows keeps its bytes."""
+    cfg, params = qwen_k128
+    xla, (s0, s1), nt_x, em = _prefill_two(cfg, params)
+    assert not xla._lane_kernel
+    rem = np.zeros(xla.kv.n_slots, np.int32)
+    rem[s0], rem[s1] = 3, 7
+    seq_x, _ = xla.decode_steps(nt_x, em, rem)
+
+    ker, _, nt_k, _ = _kernel_engine(monkeypatch, cfg, params)
+    lens0 = ker.kv.lengths.copy()
+    before = jax.tree_util.tree_map(np.asarray, ker.kv.caches)
+    seq_k, _ = ker.decode_steps(nt_k, em, rem)
+    assert ker._fused and all(c is None for _, c in ker._fused)
+    for s_ in (s0, s1):
+        np.testing.assert_array_equal(seq_k[:rem[s_], s_],
+                                      seq_x[:rem[s_], s_])
+    np.testing.assert_array_equal(ker.kv.lengths, xla.kv.lengths)
+    written = np.zeros((ker.kv.n_slots, ker.kv.max_ctx), bool)
+    for s_ in (s0, s1):
+        written[s_, lens0[s_]:lens0[s_] + rem[s_]] = True
+    for l0, lk, lx, g in zip(jax.tree_util.tree_leaves(before),
+                             jax.tree_util.tree_leaves(ker.kv.caches),
+                             jax.tree_util.tree_leaves(xla.kv.caches),
+                             jax.tree_util.tree_leaves(ker.kv._grouped)):
+        lk, lx = np.asarray(lk), np.asarray(lx)
+        np.testing.assert_allclose(lk, lx, rtol=1e-5, atol=1e-5)
+        lay = range(lk.shape[0]) if g else [None]
+        for i in lay:
+            a, b = (l0[i], lk[i]) if g else (l0, lk)
+            np.testing.assert_array_equal(b[~written], a[~written])
+
+
+def test_lane_kernel_warmup_compiles_per_chunk(monkeypatch, qwen_k128):
+    """On the lane kernel's path the fused programs are keyed by scan
+    length alone: the default warm-up compiles len(DECODE_CHUNKS), and a
+    requested ctx grid collapses onto the chunks; the XLA path keeps its
+    (steps, ctx) grid."""
+    cfg, params = qwen_k128
+    xla = ReplicaEngine(cfg, params, n_slots=2, max_ctx=128)
+    xla.warmup_decode(chunks=(1, 4), ctx_limits=(64, 128))
+    assert set(xla._fused) == {(1, 64), (1, 128), (4, 64), (4, 128)}
+
+    monkeypatch.setattr(attention, "_kernel_platform", lambda: True)
+    ker = ReplicaEngine(cfg, params, n_slots=2, max_ctx=128)
+    ker.warmup_decode(chunks=(1, 4), ctx_limits=(64, 128))
+    assert set(ker._fused) == {(1, None), (4, None)}
+    ker.warmup_decode()
+    assert set(ker._fused) == {(c, None) for c in DECODE_CHUNKS}
+
+
+def test_lane_kernel_counts_length_bounded_kv_read(monkeypatch, qwen_k128):
+    """On the lane kernel's path `kv_read` counts the positions the kernel
+    fetches: each live lane-step's length rounded up to the block (16
+    positions here), and `ctx` names no static bound."""
+    cfg, params = qwen_k128
+    monkeypatch.setattr(lane_kernel, "BLOCK_BYTES",
+                        16 * cfg.n_kv_heads * cfg.head_dim * 4)
+    eng, (s0, s1), nt, em = _kernel_engine(monkeypatch, cfg, params)
+    assert eng._lane_block == 16
+    assert (eng.kv.lengths[s0], eng.kv.lengths[s1]) == (37, 11)
+    tr = eng.tracer = Tracer(enabled=True)
+    rem = np.zeros(eng.kv.n_slots, np.int32)
+    rem[s0], rem[s1] = 3, 7
+    eng.decode_steps(nt, em, rem)
+    chunk = next(r for r in tr.records if r.name == "engine.decode_chunk")
+    assert chunk.attrs["ctx"] is None
+    # s0 at 37-39: 3 blocks each; s1 at 11-16: one block, at 17: two
+    assert chunk.attrs["kv_read"] == 3 * 48 + 6 * 16 + 32
 
 
 # --------------------------------------------------------------------------- #

@@ -6,6 +6,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import flash_decode_attention
+from repro.kernels.lane_decode_attention import lane_decode_attention
 from repro.kernels.prefill_attention import flash_prefill_attention
 from repro.kernels.rglru_kernel import rglru_pallas
 from repro.kernels.rwkv6_kernel import wkv6_pallas
@@ -51,6 +52,61 @@ def test_flash_decode_sweep(key, B, S, H, Hkv, D, dtype):
     got = flash_decode_attention(q, k, v, lens, block_k=128)
     err = jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)))
     assert float(err) < tol(dtype), f"err={float(err)}"
+
+
+@pytest.mark.parametrize("G", [1, 2])
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("live", ["none", "one", "all"])
+def test_lane_decode_matches_xla_lane_loop(key, G, layer, live):
+    """The length-bounded lane kernel against the XLA lane loop it replaces
+    (`models.attention.lane_decode_attention`, read up to the whole
+    buffer) at layer `layer` of three: lanes at lengths 1, block_k - 1,
+    block_k, block_k + 1 and max_ctx - 1, none, one or all of them live.
+    Outputs agree to f32 rounding; lanes that are not live are zero."""
+    from repro.configs import get_reduced
+    from repro.models.attention import lane_decode_attention as xla_lanes
+    B, Hkv, D, L, bk = 5, 2, 128, 64, 16
+    H = G * Hkv
+    ks = jax.random.split(key, 5)
+    q = rand(ks[0], (B, H, D), jnp.float32)
+    k_new = rand(ks[1], (B, Hkv, D), jnp.float32)
+    v_new = rand(ks[2], (B, Hkv, D), jnp.float32)
+    k = rand(ks[3], (3, B, L, Hkv, D), jnp.float32)
+    v = rand(ks[4], (3, B, L, Hkv, D), jnp.float32)
+    lens = jnp.asarray([1, bk - 1, bk, bk + 1, L - 1], jnp.int32)
+    mask = {"none": [False] * B, "one": [False, False, False, True, False],
+            "all": [True] * B}[live]
+    live_a = jnp.asarray(mask)
+    got = lane_decode_attention(q, k_new, v_new, k, v, layer, lens, live_a,
+                                block_k=bk)
+    want = xla_lanes(get_reduced("qwen3-0.6b"), q[:, None], k_new[:, None],
+                     v_new[:, None], k, v, (layer,), lens, live_a)[:, 0]
+    err = jnp.max(jnp.abs(got - want))
+    assert float(err) < TOLS[jnp.float32], f"err={float(err)}"
+    dead = ~jnp.asarray(mask)
+    assert not bool(jnp.any(got[dead] != 0))
+
+
+@pytest.mark.parametrize("Hkv", [2, 4])
+def test_lane_decode_bf16_head_pairs(key, Hkv):
+    """bf16 KV, where one 32-bit row holds a pair of KV heads: each head
+    reads its own half. Against the decode oracle over the same cache with
+    the new entry placed at each lane's length."""
+    B, G, D, L, bk = 3, 2, 128, 64, 16
+    ks = jax.random.split(key, 5)
+    q = rand(ks[0], (B, G * Hkv, D), jnp.bfloat16)
+    k_new = rand(ks[1], (B, Hkv, D), jnp.bfloat16)
+    v_new = rand(ks[2], (B, Hkv, D), jnp.bfloat16)
+    k = rand(ks[3], (2, B, L, Hkv, D), jnp.bfloat16)
+    v = rand(ks[4], (2, B, L, Hkv, D), jnp.bfloat16)
+    lens = jnp.asarray([5, 33, 63], jnp.int32)
+    got = lane_decode_attention(q, k_new, v_new, k, v, 1, lens,
+                                jnp.ones(B, bool), block_k=bk)
+    b = jnp.arange(B)
+    want = ref.decode_attention_ref(q, k[1].at[b, lens].set(k_new),
+                                    v[1].at[b, lens].set(v_new), lens + 1)
+    err = jnp.max(jnp.abs(got - want.astype(jnp.float32)))
+    assert float(err) < tol(jnp.bfloat16), f"err={float(err)}"
 
 
 @pytest.mark.parametrize("B,S,H,hs", [(1, 64, 2, 16), (2, 128, 3, 16),

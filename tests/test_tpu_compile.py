@@ -28,6 +28,7 @@ from repro.configs import get_config  # noqa: E402
 from repro.engine.replica import ReplicaEngine  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.kernels.decode_attention import flash_decode_attention  # noqa: E402
+from repro.models import attention  # noqa: E402
 from repro.kernels.prefill_attention import flash_prefill_attention  # noqa: E402
 from repro.models.model import Model  # noqa: E402
 
@@ -221,4 +222,29 @@ def test_served_prefill_fits_v5e(one_chip, engine, arch):
         jax.ShapeDtypeStruct((max_ctx,), jnp.int32, sharding=one_chip),
         scalar, scalar, None).compile()
     _check_fits(compiled)
+    _check_deployment_fits(eng, compiled)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "olmo-1b"])
+def test_served_decode_lane_kernel_fits_v5e(one_chip, engine, monkeypatch,
+                                            arch):
+    """On the TPU the benchmark's deployment of `arch` reads the live lanes
+    through the length-bounded lane kernel: the fused decode program keyed
+    by its scan length alone (32 steps, no ctx bound) holds the kernel,
+    copies no whole cache leaf and slices no multi-lane slab, and fits the
+    chip beside the deployment's weights and caches."""
+    monkeypatch.setattr(attention, "_kernel_platform", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    slots, max_ctx = _deployment(arch)
+    eng = engine(arch, "xla", n_slots=slots, max_ctx=max_ctx)
+    assert eng._lane_kernel
+    vec = lambda dt: jax.ShapeDtypeStruct((slots,), dt,  # noqa: E731
+                                          sharding=one_chip)
+    compiled = eng._build_fused(32, None).lower(
+        _specs(eng.params, one_chip), _specs(eng.kv.caches, one_chip),
+        vec(jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+        vec(jnp.int32)).compile()
+    assert _has_kernel(compiled)
+    _check_fits(compiled)
+    _check_reads_live_lanes_in_place(compiled, eng, max_ctx)
     _check_deployment_fits(eng, compiled)
